@@ -25,6 +25,8 @@ def main(argv=None):
     ap.add_argument("--waves", type=int, default=400)
     ap.add_argument("--json", default="reports/abort_rates.json")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     scale = 1.0
     rows = sweep("tpcc", lanes=[64, 128], waves=args.waves, scale=scale,

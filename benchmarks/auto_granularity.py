@@ -17,6 +17,8 @@ def main(argv=None):
     ap.add_argument("--lanes", type=int, nargs="+", default=[64, 128])
     ap.add_argument("--json", default="reports/auto_granularity.json")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     rows = []
     rows += sweep("tpcc", ccs=["occ"], lanes=args.lanes, grans=(0, 1),

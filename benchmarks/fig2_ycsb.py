@@ -24,6 +24,8 @@ def main(argv=None):
     ap.add_argument("--backend", choices=("jnp", "pallas"), default="jnp")
     ap.add_argument("--json", default="reports/fig2_ycsb.json")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     n_keys = 10_000_000 if args.full else 1_000_000
     print(f"# Fig 2a (coarse) + 2b (fine), {n_keys} keys "

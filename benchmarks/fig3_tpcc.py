@@ -26,6 +26,8 @@ def main(argv=None):
     ap.add_argument("--backend", choices=("jnp", "pallas"), default="jnp")
     ap.add_argument("--json", default="reports/fig3_tpcc.json")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     scale = 1.0
     print(f"# Fig 3a (coarse) + 3b (fine), 8 warehouses, scale={scale} "

@@ -39,6 +39,8 @@ def main(argv=None):
                          "labeled cc/gran/rate) — REPRO_TRACE=1 also "
                          "enables it")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import os
     trace_path = args.trace

@@ -88,6 +88,8 @@ def main(argv=None):
                          "throughput instead of goodput)")
     ap.add_argument("--json", default="reports/scan_mix.json")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     open_loop = not args.closed_loop
 
     rows = []

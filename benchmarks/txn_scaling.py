@@ -1,7 +1,7 @@
 """Beyond-paper: distributed txn-engine scaling (the paper's section 5:
 "perform similar evaluations on distributed CC mechanisms").
 
-Runs the shard_map wave on 1/2/4/8 host devices (same *global* lane and
+Runs the shard_map wave on 1/2/4/8 devices (same *global* lane and
 record counts), measuring committed txns per second of wall time and the
 per-wave collective bytes — the weak-scaling story of the routed engine —
 for BOTH the single-version mechanism (occ) and the sharded multi-version
@@ -30,6 +30,9 @@ bit-packed wire beats >= 4x) from ``distributed.wire_bytes_per_wave``.
     PYTHONPATH=src python -m benchmarks.txn_scaling \\
         [--waves N] [--pipeline-depth D] [--shards 1 8] [--json out.json]
 
+The shard counts stop at the devices present: up to 8 forced host
+devices when JAX runs on the CPU, up to ``jax.device_count()`` chips on a
+TPU (the parent process never imports JAX, so the child owns the chips).
 ``--shards`` (or ``REPRO_TXN_SHARDS=1,8``) subsets the shard sweep — the
 CI pallas-interpret smoke runs the 1/8 endpoints only, since every grid
 point pays an interpret-mode compile there.
@@ -45,11 +48,17 @@ import textwrap
 
 PROG = textwrap.dedent("""
     import os, sys, time, json
+    # Host-device override: it shapes only the CPU platform (8 devices for
+    # a run without an accelerator); a TPU machine shards over its chips.
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     sys.path.insert(0, "src")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.core import distributed as D, types as t
     from repro.analysis.roofline import collective_bytes_from_hlo
+    MAX_SHARDS = jax.device_count()
+    print(f"devices: {MAX_SHARDS} x {jax.devices()[0].device_kind}")
 
     K, N = 16, 1_000_000
     # Global lane count (kept at the default for real sweeps; the CI
@@ -63,7 +72,7 @@ PROG = textwrap.dedent("""
     # Shard-count subset (e.g. "1,8" for the CI interpret-mode smoke,
     # where each grid point pays a pallas interpret compile).
     SHARDS = tuple(int(s) for s in os.environ.get(
-        "REPRO_TXN_SHARDS", "1,2,4,8").split(","))
+        "REPRO_TXN_SHARDS", "1,2,4,8").split(",") if int(s) <= MAX_SHARDS)
     rows = []
 
     # shards=0 anchor: the local (single-device) engine at the same global
@@ -200,7 +209,7 @@ PROG = textwrap.dedent("""
 
     for cc in ("occ", "mvcc"):
         for gran in (0, 1):
-            for ns in [n for n in (1, 8) if n in SHARDS]:
+            for ns in sorted({1, max(SHARDS)} & set(SHARDS)):
                 mesh = jax.make_mesh((ns,), ("data",))
                 T_loc = GLOBAL_LANES // ns
                 cfg = D.DistConfig(n_records=N, n_groups=2,
@@ -271,8 +280,9 @@ def main(argv=None):
                  f"got {args.pipeline_depth}")
     if args.shards is not None and any(
             s < 1 or s > 8 or s & (s - 1) for s in args.shards):
-        ap.error(f"--shards must be powers of two in [1, 8] (the forced "
-                 f"host-device count), got {args.shards}")
+        ap.error(f"--shards must be powers of two in [1, 8] (counts "
+                 f"above the devices present are skipped), got "
+                 f"{args.shards}")
     env = dict(os.environ)
     if args.waves is not None:
         env["REPRO_TXN_WAVES"] = str(args.waves)

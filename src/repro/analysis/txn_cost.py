@@ -253,7 +253,7 @@ def wave_cost(cc: str, s: WaveShape, distributed: bool = False) -> dict:
 
 
 def txn_cost(cc: str, s: WaveShape, distributed: bool = False,
-             chip: str = peaks.DEFAULT_CHIP) -> dict:
+             chip: str = peaks.V5E) -> dict:
     """The dashboard row fields: per-ATTEMPT per-transaction traffic and
     the mechanism's place on ``chip``'s roofline.
 

@@ -61,8 +61,9 @@ implementations:
 - ``jnp``    — XLA gather/scatter (the oracles in ``kernels/ref.py`` and the
   helpers in ``core/claims.py`` are the same computations);
 - ``pallas`` — the TPU-native kernels behind ``kernels/ops.py`` (interpret
-  mode off-TPU), every op a scalar-prefetch row-DMA grid or an aliased-output
-  sequential-grid scatter.
+  mode on the CPU), every table op a block row-DMA gather or an
+  aliased-output block read-modify-write over the packed table layout of
+  ``kernels/rows.py``.
 
 Both decode the one claim-word layout in ``core/claimword.py`` and are
 bit-identical (tests/test_backend_parity.py, tests/test_kernels.py).  CC
@@ -71,8 +72,8 @@ per wave and use only this surface, so a new mechanism gets TPU execution for
 free and a new backend only has to implement these ``N_OPS`` ops.
 
 ``resolve`` honors ``cfg.lane_block`` on the pallas backend: the row-DMA
-kernels tile the wave into LB-lane blocks (kernels/wave_commit.py
-``pick_lane_block``; 0 = auto from table width) and the override threads
+kernels tile the wave into LB-lane blocks (kernels/rows.py
+``pick_lane_block``; 0 = the least block) and the override threads
 through every lane-block kernel call.
 """
 from __future__ import annotations
@@ -204,11 +205,11 @@ class JnpBackend:
 
 
 class PallasBackend:
-    """TPU-native kernels (compiled on TPU, interpret mode elsewhere).
+    """TPU-native kernels (compiled on a TPU, interpret mode on the CPU).
 
     ``lane_block`` threads the lane-block tiling override (LB lanes per
     grid step; 0 = auto) into every row-DMA kernel — see
-    kernels/wave_commit.pick_lane_block and ``resolve``."""
+    kernels/rows.pick_lane_block and ``resolve``."""
     name = "pallas"
     use_pallas = True
 

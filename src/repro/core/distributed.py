@@ -132,7 +132,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import shard_map
 
 from repro.core import admission
 from repro.core import backend as kb
@@ -380,6 +379,13 @@ class DistConfig:
 
 def _axes(mesh) -> tuple:
     return tuple(mesh.axis_names)
+
+
+def _auto(mesh):
+    """``mesh`` with plain auto axes: whatever axis types the caller's mesh
+    carries (``jax.make_mesh`` makes explicit ones), the engine's sharded
+    state stays out of the arrays' types, so host code may index it."""
+    return jax.sharding.Mesh(mesh.devices, mesh.axis_names)
 
 
 def n_shards(mesh) -> int:
@@ -877,10 +883,11 @@ def make_wave_fn(cfg: DistConfig, mesh):
 
     spec_ops = _spec_ops(mesh)
     tab_spec = (spec_ops,) * (4 if mv else 2)
-    wave = shard_map(
-        local_wave, mesh=mesh,
+    wave = jax.shard_map(
+        local_wave, mesh=_auto(mesh),
         in_specs=(spec_ops, spec_ops, spec_ops, spec_ops, tab_spec, P()),
-        out_specs=(spec_ops, tab_spec, spec_ops))
+        out_specs=(spec_ops, tab_spec, spec_ops),
+        check_vma=False)
     return wave
 
 
@@ -939,10 +946,11 @@ def make_run_fn(cfg: DistConfig, mesh, n_waves: int):
 
     spec = _spec_stack(mesh)
     tab_spec = (_spec_ops(mesh),) * (4 if mv else 2)
-    run = shard_map(
-        local_run, mesh=mesh,
+    run = jax.shard_map(
+        local_run, mesh=_auto(mesh),
         in_specs=(spec, spec, spec, spec, tab_spec, P()),
-        out_specs=(spec, tab_spec, spec))
+        out_specs=(spec, tab_spec, spec),
+        check_vma=False)
     return run
 
 
@@ -1065,10 +1073,11 @@ def make_open_wave_fn(cfg: DistConfig, mesh):
     spec = _spec_ops(mesh)
     tab_spec = (spec,) * (4 if mv else 2)
     q_spec = (spec,) * 10
-    wave = shard_map(
-        local_wave, mesh=mesh,
+    wave = jax.shard_map(
+        local_wave, mesh=_auto(mesh),
         in_specs=(spec, spec, spec, spec, spec, tab_spec, q_spec, P()),
-        out_specs=(spec, tab_spec, q_spec, spec))
+        out_specs=(spec, tab_spec, q_spec, spec),
+        check_vma=False)
     return wave
 
 
@@ -1232,10 +1241,11 @@ def make_open_run_fn(cfg: DistConfig, mesh, n_waves: int):
     spec1 = _spec_ops(mesh)
     tab_spec = (spec1,) * (4 if mv else 2)
     q_spec = (spec1,) * 10
-    run = shard_map(
-        local_run, mesh=mesh,
+    run = jax.shard_map(
+        local_run, mesh=_auto(mesh),
         in_specs=(spec, spec, spec, spec, spec, tab_spec, q_spec, P()),
-        out_specs=(spec, tab_spec, q_spec, spec))
+        out_specs=(spec, tab_spec, q_spec, spec),
+        check_vma=False)
     return run
 
 
@@ -1336,15 +1346,23 @@ def init_tables(cfg: DistConfig, mesh):
       ring of core/mvstore.py (slot 0 live at begin 0, head 0) plus the two
       claim channels, all range-sharded over the padded record space.
     """
+    from jax.sharding import NamedSharding
     ns = n_shards(mesh)
     rec_per = -(-cfg.n_records // ns)
     N, G = ns * rec_per, cfg.n_groups
-    claim_w = jnp.full((N, G), t.NO_CLAIM, jnp.uint32)
-    if cfg.is_mv:
-        mv_begin, mv_head, _ = mvstore.mv_init(N, cfg.mv_depth, G)
-        claim_r = jnp.full((N, G), t.NO_CLAIM, jnp.uint32)
-        return (claim_w, claim_r, mv_begin, mv_head)
-    return (jnp.zeros((N, G), jnp.uint32), claim_w)
+    ax = _axes(mesh)
+
+    def make():
+        claim_w = jnp.full((N, G), t.NO_CLAIM, jnp.uint32)
+        if cfg.is_mv:
+            mv_begin, mv_head, _ = mvstore.mv_init(N, cfg.mv_depth, G)
+            claim_r = jnp.full((N, G), t.NO_CLAIM, jnp.uint32)
+            return (claim_w, claim_r, mv_begin, mv_head)
+        return (jnp.zeros((N, G), jnp.uint32), claim_w)
+
+    # Built in place on the mesh: each device holds its own range shard.
+    return jax.jit(make, out_shardings=NamedSharding(
+        _auto(mesh), P(ax if len(ax) > 1 else ax[0])))()
 
 
 def abstract_args(cfg: DistConfig, mesh):

@@ -424,12 +424,12 @@ class EngineConfig:
                                 # bit-identical either way (DESIGN.md
                                 # section 5, tests/test_wave_commit.py).
     lane_block: int = 0         # Lanes per pallas grid step (LB): the
-                                # kernels tile (T, K) into (T // LB) lane
-                                # blocks, LB*K row DMAs in flight per step.
-                                # 0 = auto from the table width
-                                # (kernels/wave_commit.pick_lane_block);
-                                # explicit values snap down to a divisor of
-                                # `lanes`.  jnp backend ignores it.
+                                # kernels walk the wave in blocks of LB*K
+                                # ops, their row DMAs in flight together.
+                                # 0 = the least LB with LB*K a multiple of
+                                # 128 (kernels/rows.pick_lane_block);
+                                # explicit values round up to the next such
+                                # LB.  jnp backend ignores it.
     max_extent: int = 1         # Widest op interval the workload emits
                                 # ([key, key+extent) — TxnBatch.op_extent).
                                 # 1 = point ops only: the scan validation

@@ -1,73 +1,34 @@
 """Fused claim-scatter kernel: pack + scatter-min claim words on-chip.
 
-The jnp backend claims in two steps — pack `(inv_wave << 16) | prio16` words
-(core/claimword.py), then an XLA scatter-min into the claim table
-(claims.scatter_claims).  This kernel fuses both: the claim word is packed in
-registers from the prefetched inv_wave and the op's prio16, and min-installed
-into the aliased claim-table row the grid step just DMA'd.  The packed word
-never exists in HBM, and the pallas backend stops silently falling back to
-XLA for claims (ROADMAP open item; DESIGN.md section 5).
+The jnp backend claims in two steps — pack ``(inv_wave << 16) | prio16``
+words (core/claimword.py), then an XLA scatter-min into the claim table
+(claims.scatter_claims).  This kernel installs the packed words with the
+block read-modify-write of ``rows.scatter_call`` into the aliased packed
+claim table.
 
 Why min: claim words are arranged so *lower = stronger* — the current wave's
 tag is numerically below every stale wave's and in-wave priority breaks ties
 — so min over duplicate cells picks the strongest claimant, the vectorized
 replacement for the paper's CAS races (core/claims.py).  Min is commutative
-and idempotent, so the sequential-grid visit order cannot be observed:
-bit-identical to the XLA scatter-min.
-
-Masked ops clamp their DMA to row 0 and install EMPTY_WORD (the identity of
-min), leaving the row unchanged.
+and idempotent, so the visit order cannot be observed: bit-identical to the
+XLA scatter-min.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.claimword import EMPTY_WORD, PRIO16_MASK, WAVE_SHIFT
-
-
-def _kernel(keys_ref, ivw_ref, grp_ref, prio_ref, do_ref, row_ref, out_ref):
-    # Accumulate through the *output* ref (see occ_commit.py): sequential
-    # grid steps revisiting a row must read back their predecessors' claims.
-    del row_ref
-    G = out_ref.shape[-1]
-    word = ((ivw_ref[0] << WAVE_SHIFT)
-            | (prio_ref[0, 0] & jnp.uint32(PRIO16_MASK)))
-    g = grp_ref[0, 0]
-    sel = (jnp.arange(G, dtype=jnp.int32) == g) & do_ref[0, 0]
-    cand = jnp.where(sel, word, jnp.uint32(EMPTY_WORD))
-    out_ref[0, :] = jnp.minimum(out_ref[0, :], cand)
+from repro.core.claimword import PRIO16_MASK, WAVE_SHIFT
+from repro.kernels import rows as rw
 
 
 def claim_scatter_pallas(table: jax.Array, keys: jax.Array,
                          groups: jax.Array, prio: jax.Array, do: jax.Array,
-                         inv_wave: jax.Array,
+                         inv_wave: jax.Array, lane_block: int = 0,
                          interpret: bool = False) -> jax.Array:
     """table' with the wave's claim words min-installed — see
     ref.claim_scatter."""
-    T, K = keys.shape
-    G = table.shape[1]
-    ivw = jnp.reshape(inv_wave.astype(jnp.uint32), (1,))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # keys, inv_wave
-        grid=(T, K),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda t, k, keys, ivw: (t, k)),   # groups
-            pl.BlockSpec((1, 1), lambda t, k, keys, ivw: (t, k)),   # prio
-            pl.BlockSpec((1, 1), lambda t, k, keys, ivw: (t, k)),   # do
-            pl.BlockSpec((1, G),
-                         lambda t, k, keys, ivw: (jnp.maximum(keys[t, k], 0),
-                                                  0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, G), lambda t, k, keys, ivw: (jnp.maximum(keys[t, k], 0), 0)),
-    )
-    return pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
-        input_output_aliases={5: 0},  # table is operand 5 counting prefetch
-        interpret=interpret,
-    )(keys, ivw, groups, prio.astype(jnp.uint32), do & (keys >= 0), table)
+    words = ((inv_wave.astype(jnp.uint32) << WAVE_SHIFT)
+             | (prio.astype(jnp.uint32) & PRIO16_MASK))
+    return rw.scatter_call(rw.umin, table, keys, groups, words, do, False,
+                           lane_block, interpret, "claim_scatter")

@@ -3,13 +3,11 @@
 A multi-version read walks the record's version chain for the newest version
 visible at its snapshot timestamp.  On the paper's CPU platform that is a
 pointer chase per read; here the chain is a fixed-depth ring
-(core/mvstore.py), so the TPU-native formulation is the same lane-block
-row-DMA grid as the claim-table gathers (kernels/occ_validate.py): op keys
-are prefetched into SMEM, each ``(T // LB,)`` grid step DMAs its block's
-LB*K whole begin-timestamp rings [D, G] HBM->VMEM (the whole read stream in
-flight at once — kernels/wave_commit.py), and the VPU does the visibility
-scan vectorized over the block — all D slots of all block ops compared at
-once instead of a serial chain walk.
+(core/mvstore.py), so the TPU-native formulation is the block row-DMA gather
+of kernels/occ_validate.py (``rows.gather_call``): each op's whole begin ring
+[D, G] (D*G packed words) rides in together, and the VPU does the
+visibility scan over all D slots of all block ops at once instead of a
+serial chain walk.
 
 Granularity is the visibility width (DESIGN.md section 9): fine checks the
 op's own group's begin timestamp per slot, coarse reduces each slot over the
@@ -20,45 +18,15 @@ slots carry MV_EMPTY begins and are never visible.  When NO retained slot is
 visible the snapshot has been reclaimed by the ring's epoch advance: ok is
 False and the caller aborts the reader — it can never read a recycled slot.
 
-Masked ops (key < 0) clamp their DMA to row 0 and are forced to
-(slot 0, ok False), matching the jnp gather's fill path.
+Masked ops (key < 0) are forced to (slot 0, ok False), matching the jnp
+gather's fill path.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.wave_commit import (_row_dmas, _start, _wait,
-                                       pick_lane_block)
-
-
-def _kernel(fine, D, G, LB, K, keys_ref, ts_ref, kv_b, grp_b, tbl, slot_b,
-            ok_b, rows_s, sem):
-    LBK = LB * K
-    t0 = pl.program_id(0) * LB
-    _row_dmas(_start, keys_ref, tbl, rows_s, sem, t0, LB, K)
-    _row_dmas(_wait, keys_ref, tbl, rows_s, sem, t0, LB, K)
-
-    rows = rows_s[...]                                   # uint32[LBK, D, G]
-    ts = ts_ref[0]
-    if fine:
-        gb = grp_b[...].reshape(LBK)
-        sel = (jnp.arange(G, dtype=jnp.int32)[None, None, :]
-               == gb[:, None, None])
-        eff = jnp.where(sel, rows, jnp.uint32(0)).max(axis=2)
-    else:
-        eff = rows.max(axis=2)                           # uint32[LBK, D]
-    score = jnp.where(eff <= ts, eff + jnp.uint32(1), jnp.uint32(0))
-    best = score.max(axis=1)                             # (LBK,)
-    slot = jnp.where(score == best[:, None],
-                     jnp.arange(D, dtype=jnp.int32)[None, :], D).min(axis=1)
-    live = kv_b[...].reshape(LBK) >= 0
-    slot_b[...] = jnp.where(live, slot, 0).reshape(LB, K)
-    ok_b[...] = (live & (best > 0)).reshape(LB, K)
+from repro.kernels import rows as rw
 
 
 def mv_gather_pallas(begin: jax.Array, keys: jax.Array, groups: jax.Array,
@@ -66,26 +34,32 @@ def mv_gather_pallas(begin: jax.Array, keys: jax.Array, groups: jax.Array,
                      interpret: bool = False
                      ) -> tuple[jax.Array, jax.Array]:
     """(slot int32[T, K], ok bool[T, K]) — see ref.mv_gather."""
-    T, K = keys.shape
     D, G = begin.shape[1], begin.shape[2]
-    LB = pick_lane_block(T, K, G * D, lane_block)
-    LBK = LB * K
-    tsa = jnp.reshape(ts.astype(jnp.uint32), (1,))
-    blk = pl.BlockSpec((LB, K), lambda i, keys, ts: (i, 0))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # keys, ts
-        grid=(T // LB,),
-        in_specs=[blk, blk,
-                  pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=(blk, blk),
-        scratch_shapes=[pltpu.VMEM((LBK, D, G), jnp.uint32),
-                        pltpu.SemaphoreType.DMA((LBK,))],
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, fine, D, G, LB, K),
-        grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((T, K), jnp.int32),
-                   jax.ShapeDtypeStruct((T, K), jnp.bool_)),
-        interpret=interpret,
-    )(keys, tsa, keys, groups, begin)
+    def compute(words, scalars, cols):
+        (ts_,) = scalars
+        key_c, grp_c = cols[1], cols[2]
+        scores = []
+        for d in range(D):
+            slot_words = words[d * G:(d + 1) * G]
+            if fine:
+                eff = rw.group_word(slot_words, grp_c)
+            else:
+                eff = slot_words[0]
+                for w in slot_words[1:]:
+                    eff = rw.umax(eff, w)
+            # visible (eff <= ts, unsigned) -> eff + 1, else 0
+            scores.append(jnp.where(rw.ult(ts_, eff), 0, eff + 1))
+        best = scores[0]
+        for s in scores[1:]:
+            best = rw.umax(best, s)
+        slot = jnp.full(best.shape, D, jnp.int32)
+        for d in reversed(range(D)):
+            slot = jnp.where(scores[d] == best, d, slot)
+        live = key_c >= 0
+        return [jnp.where(live, slot, 0), live & (best != 0)]
+
+    slot, ok = rw.gather_call(compute, begin, keys, [groups],
+                              [ts.astype(jnp.uint32)], 2, lane_block,
+                              interpret, "mv_gather")
+    return slot, ok != 0

@@ -1,60 +1,28 @@
 """OCC commit kernel: install version bumps for committed write ops.
 
-Aliased-output scatter: the version table is both input and output
-(input_output_aliases), the grid walks the wave's write ops in serialization
-order, and each step DMAs the op's row, adds a one-hot increment, and writes
-it back.  The TPU grid is *sequential*, which is what makes read-modify-write
-on revisited rows well-defined — the same property the engine's claim tables
-get from XLA scatter combiners.
-
-Hardware note: on real TPUs, revisiting an output block at non-consecutive
-grid steps forces a writeback+refetch of that row between visits; correctness
-relies on the alias (validated exhaustively in interpret mode against
-ref.occ_commit, including duplicate-row cases).  Multiple bumps of the same
-cell are semantically idempotent for OCC (any bump invalidates readers).
+A block read-modify-write (``rows.scatter_call``): the packed version table
+is both input and output (input_output_aliases), each block of ops fetches
+its rows, adds one per committed write to the op's cell (duplicates within
+a block accumulate), and writes the final rows back; the sequential grid
+orders the blocks, so duplicate bumps across blocks accumulate too.
+Validated against ref.occ_commit, including duplicate-row cases.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import rows as rw
 
 
-def _kernel(keys_ref, grp_ref, do_ref, row_ref, out_ref):
-    # Accumulate through the *output* ref: the aliased out buffer holds the
-    # original table, and sequential grid steps revisiting a row read back
-    # their predecessors' writes.  (Reading the input ref instead would see
-    # the pristine pre-kernel row and lose duplicate bumps.)
-    del row_ref
-    G = out_ref.shape[-1]
-    g = grp_ref[0, 0]
-    bump = ((jnp.arange(G, dtype=jnp.int32) == g)
-            & do_ref[0, 0]).astype(jnp.uint32)
-    out_ref[0, :] = out_ref[0, :] + bump
+def _add(cell, val):
+    return cell + val
 
 
 def occ_commit_pallas(wts: jax.Array, keys: jax.Array, groups: jax.Array,
-                      do: jax.Array, interpret: bool = False) -> jax.Array:
+                      do: jax.Array, lane_block: int = 0,
+                      interpret: bool = False) -> jax.Array:
     """wts' with +1 at each (key[t,k], group[t,k]) where do[t,k]."""
-    T, K = keys.shape
-    G = wts.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(T, K),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda t, k, keys: (t, k)),      # groups
-            pl.BlockSpec((1, 1), lambda t, k, keys: (t, k)),      # do
-            pl.BlockSpec((1, G),
-                         lambda t, k, keys: (jnp.maximum(keys[t, k], 0), 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, G), lambda t, k, keys: (jnp.maximum(keys[t, k], 0), 0)),
-    )
-    return pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(wts.shape, wts.dtype),
-        input_output_aliases={3: 0},  # wts is operand 3 counting the prefetch
-        interpret=interpret,
-    )(keys, groups, do & (keys >= 0), wts)
+    return rw.scatter_call(_add, wts, keys, groups,
+                           jnp.ones(keys.shape, jnp.int32), do, False,
+                           lane_block, interpret, "occ_commit")

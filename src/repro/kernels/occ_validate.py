@@ -1,86 +1,46 @@
-"""OCC read-set validation kernel.
+"""OCC read-set validation kernels.
 
 The hot loop of optimistic commit: for every read op, fetch the claimed-writer
 word of its (record, group) cell and compare priorities.  On the paper's CPU
 platform this is a pointer chase per read; the TPU-native formulation is a
-scalar-prefetch-driven DMA: op keys are prefetched into SMEM and claim rows
-move HBM->VMEM by explicit ``make_async_copy`` row DMAs, then the VPU does
-the tag/priority compare.
-
-The grid is LANE BLOCKS (kernels/wave_commit.py): ``(T // LB,)`` with an
-LB-lane x K-slot block per step instead of the old one-op-per-step
-``(T, K)`` grid.  A step issues the row fetches for all LB*K ops of its
-block back-to-back (the whole read stream in flight at once), waits once,
-and runs the compares fully vectorized over the block — amortizing the
-per-step grid overhead that dominated at one row DMA per step.  ``LB`` is
-auto-chosen from the table width (``pick_lane_block``) with an
-``EngineConfig.lane_block`` override; LB=1 recovers the per-op tiling.
+scalar-prefetch-driven DMA: each op's packed table row index is prefetched
+into SMEM and rows move HBM->VMEM by explicit ``make_async_copy`` DMAs, a
+whole block of ops in flight at once (``rows.gather_call``), then the VPU
+does the tag/priority compare for the block.
 
 Granularity is the compare width (DESIGN.md section 2): fine compares the
-op's own group column, coarse reduces over the whole row (G is small — one
-8/16-byte row per op — so the coarse reduce is free; the DMA is the cost, and
-it is identical for both granularities, matching the paper's "fine-grained
+op's own group word, coarse reduces over the record's G words (the row is in
+VMEM either way, so the coarse reduce is free; the DMA is the cost, and it is
+identical for both granularities, matching the paper's "fine-grained
 timestamps have no measurable overhead").
 
-Three kernels share the one lane-block row-DMA grid:
+Three kernels share the one gather grid:
 
 - ``occ_validate_pallas`` — conflict bool at one granularity (OCC's hot loop);
 - ``occ_validate_dual_pallas`` — fine AND coarse verdicts from the same row
   fetch, so AutoGran's double probe costs one DMA per op, not two;
 - ``claim_probe_pallas`` — the raw strongest-claimant prio16 (NO_PRIO when
-  the cell is unclaimed this wave), for mechanisms that need the priority
-  itself rather than a verdict (TicToc's extension rule, SwissTM, 2PL,
-  Adaptive; DESIGN.md section 5).
+  the cell is unclaimed this wave or the op is masked).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.claimword import NO_PRIO, live_prio
-from repro.kernels.wave_commit import (_row_dmas, _start, _wait,
-                                       pick_lane_block)
+from repro.core.claimword import NO_PRIO
+from repro.kernels import rows as rw
 
 
-def _table_prio(rows, ivw, gb, fine, G):
-    """Strongest live claimant per block op from its fetched row."""
-    pr = live_prio(rows, ivw)                            # (LBK, G)
+def _table_prio(words, ivw, cols, fine):
+    """Strongest live claimant per block op from its record's words."""
+    key_c, grp_c = cols[1], cols[2]
     if fine:
-        sel = jnp.arange(G, dtype=jnp.int32)[None, :] == gb[:, None]
-        return jnp.where(sel, pr, jnp.uint32(NO_PRIO)).min(axis=1)
-    return pr.min(axis=1)
-
-
-def _kernel(fine, G, LB, K, keys_ref, ivw_ref, grp_b, prio_b, chk_b, tbl,
-            out_b, rows_s, sem):
-    LBK = LB * K
-    t0 = pl.program_id(0) * LB
-    _row_dmas(_start, keys_ref, tbl, rows_s, sem, t0, LB, K)
-    _row_dmas(_wait, keys_ref, tbl, rows_s, sem, t0, LB, K)
-    gb = grp_b[...].reshape(LBK)
-    wprio = _table_prio(rows_s[...], ivw_ref[0], gb, fine, G)
-    conf = chk_b[...].reshape(LBK) & (wprio < prio_b[...].reshape(LBK))
-    out_b[...] = conf.reshape(LB, K)
-
-
-def _val_specs(T, K, G, LB, n_scalar_ins, n_outs):
-    """Shared lane-block grid spec: blocked per-op scalars, ANY table,
-    blocked outputs, row scratch + DMA semaphores."""
-    LBK = LB * K
-    blk = pl.BlockSpec((LB, K), lambda i, keys, ivw: (i, 0))
-    return pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T // LB,),
-        in_specs=[blk] * n_scalar_ins
-        + [pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=blk if n_outs == 1 else (blk,) * n_outs,
-        scratch_shapes=[pltpu.VMEM((LBK, G), jnp.uint32),
-                        pltpu.SemaphoreType.DMA((LBK,))],
-    )
+        pr = rw.live_prio(rw.group_word(words, grp_c), ivw)
+    else:
+        pr = rw.live_prio(words[0], ivw)
+        for w in words[1:]:
+            pr = jnp.minimum(pr, rw.live_prio(w, ivw))
+    return jnp.where(key_c >= 0, pr, NO_PRIO)
 
 
 def occ_validate_pallas(claim_w: jax.Array, keys: jax.Array,
@@ -88,35 +48,16 @@ def occ_validate_pallas(claim_w: jax.Array, keys: jax.Array,
                         check: jax.Array, inv_wave: jax.Array, fine: bool,
                         lane_block: int = 0,
                         interpret: bool = False) -> jax.Array:
-    """conflict bool[T, K] — see ref.occ_validate for the oracle.  Masked
-    ops (key < 0) clamp their DMA to row 0; ``check`` zeroes their result."""
-    T, K = keys.shape
-    G = claim_w.shape[1]
-    LB = pick_lane_block(T, K, G, lane_block)
-    ivw = jnp.reshape(inv_wave.astype(jnp.uint32), (1,))
-    return pl.pallas_call(
-        functools.partial(_kernel, fine, G, LB, K),
-        grid_spec=_val_specs(T, K, G, LB, 3, 1),
-        out_shape=jax.ShapeDtypeStruct((T, K), jnp.bool_),
-        interpret=interpret,
-    )(keys, ivw, groups, myprio.astype(jnp.uint32), check, claim_w)
+    """conflict bool[T, K] — see ref.occ_validate for the oracle."""
 
+    def compute(words, scalars, cols):
+        wprio = _table_prio(words, scalars[0], cols, fine)
+        return [(cols[4] != 0) & rw.ult(wprio, cols[3])]
 
-def _dual_kernel(G, LB, K, keys_ref, ivw_ref, grp_b, prio_b, chk_b, tbl,
-                 fine_b, coarse_b, rows_s, sem):
-    LBK = LB * K
-    t0 = pl.program_id(0) * LB
-    _row_dmas(_start, keys_ref, tbl, rows_s, sem, t0, LB, K)
-    _row_dmas(_wait, keys_ref, tbl, rows_s, sem, t0, LB, K)
-    pr = live_prio(rows_s[...], ivw_ref[0])              # (LBK, G)
-    gb = grp_b[...].reshape(LBK)
-    sel = jnp.arange(G, dtype=jnp.int32)[None, :] == gb[:, None]
-    fprio = jnp.where(sel, pr, jnp.uint32(NO_PRIO)).min(axis=1)
-    cprio = pr.min(axis=1)
-    chk = chk_b[...].reshape(LBK)
-    myp = prio_b[...].reshape(LBK)
-    fine_b[...] = (chk & (fprio < myp)).reshape(LB, K)
-    coarse_b[...] = (chk & (cprio < myp)).reshape(LB, K)
+    (conf,) = rw.gather_call(compute, claim_w, keys,
+                             [groups, myprio, check], [inv_wave], 1,
+                             lane_block, interpret, "occ_validate")
+    return conf != 0
 
 
 def occ_validate_dual_pallas(claim_w: jax.Array, keys: jax.Array,
@@ -126,30 +67,17 @@ def occ_validate_dual_pallas(claim_w: jax.Array, keys: jax.Array,
                              ) -> tuple[jax.Array, jax.Array]:
     """(fine, coarse) conflict bool[T, K] from ONE row DMA per op — the
     AutoGran double probe without the double fetch."""
-    T, K = keys.shape
-    G = claim_w.shape[1]
-    LB = pick_lane_block(T, K, G, lane_block)
-    ivw = jnp.reshape(inv_wave.astype(jnp.uint32), (1,))
-    return pl.pallas_call(
-        functools.partial(_dual_kernel, G, LB, K),
-        grid_spec=_val_specs(T, K, G, LB, 3, 2),
-        out_shape=(jax.ShapeDtypeStruct((T, K), jnp.bool_),
-                   jax.ShapeDtypeStruct((T, K), jnp.bool_)),
-        interpret=interpret,
-    )(keys, ivw, groups, myprio.astype(jnp.uint32), check, claim_w)
 
+    def compute(words, scalars, cols):
+        chk = cols[4] != 0
+        return [chk & rw.ult(_table_prio(words, scalars[0], cols, f),
+                             cols[3])
+                for f in (True, False)]
 
-def _probe_kernel(fine, G, LB, K, keys_ref, ivw_ref, kv_b, grp_b, tbl,
-                  out_b, rows_s, sem):
-    LBK = LB * K
-    t0 = pl.program_id(0) * LB
-    _row_dmas(_start, keys_ref, tbl, rows_s, sem, t0, LB, K)
-    _row_dmas(_wait, keys_ref, tbl, rows_s, sem, t0, LB, K)
-    gb = grp_b[...].reshape(LBK)
-    wprio = _table_prio(rows_s[...], ivw_ref[0], gb, fine, G)
-    live = kv_b[...].reshape(LBK) >= 0
-    out_b[...] = jnp.where(live, wprio,
-                           jnp.uint32(NO_PRIO)).reshape(LB, K)
+    fine, coarse = rw.gather_call(compute, claim_w, keys,
+                                  [groups, myprio, check], [inv_wave], 2,
+                                  lane_block, interpret, "occ_validate_dual")
+    return fine != 0, coarse != 0
 
 
 def claim_probe_pallas(table: jax.Array, keys: jax.Array, groups: jax.Array,
@@ -157,13 +85,10 @@ def claim_probe_pallas(table: jax.Array, keys: jax.Array, groups: jax.Array,
                        interpret: bool = False) -> jax.Array:
     """Strongest live claimant prio16 per op (uint32[T, K]; NO_PRIO when the
     cell is unclaimed this wave or the op is masked) — see ref.claim_probe."""
-    T, K = keys.shape
-    G = table.shape[1]
-    LB = pick_lane_block(T, K, G, lane_block)
-    ivw = jnp.reshape(inv_wave.astype(jnp.uint32), (1,))
-    return pl.pallas_call(
-        functools.partial(_probe_kernel, fine, G, LB, K),
-        grid_spec=_val_specs(T, K, G, LB, 2, 1),
-        out_shape=jax.ShapeDtypeStruct((T, K), jnp.uint32),
-        interpret=interpret,
-    )(keys, ivw, keys, groups, table)
+
+    def compute(words, scalars, cols):
+        return [_table_prio(words, scalars[0], cols, fine)]
+
+    (wprio,) = rw.gather_call(compute, table, keys, [groups], [inv_wave], 1,
+                              lane_block, interpret, "claim_probe_raw")
+    return wprio.astype(jnp.uint32)

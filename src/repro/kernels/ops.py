@@ -1,16 +1,16 @@
 """Public kernel entry points.
 
-Each op auto-selects the execution path:
-  - on TPU: the Pallas kernel (compiled);
-  - elsewhere (this CPU container, tests): either the jnp reference (fast,
-    used inside jitted models) or the Pallas kernel in interpret mode
-    (tests/test_kernels.py validates kernel == reference across shape/dtype
-    sweeps).
+Each op picks its execution path:
+  - the Pallas kernel, compiled on a TPU and run in interpret mode on the
+    CPU (the tests; tests/test_kernels.py validates kernel == reference
+    across shape sweeps) — any other platform raises (``_interp``);
+  - or the jnp reference in ``kernels/ref.py``.
 
-Set ``REPRO_KERNELS`` ("pallas" | "ref") or pass use_pallas/interpret
-explicitly to override; models route through these wrappers so the same model
-code runs on both backends.  The env var is resolved *per call* (not at
-import time), so tests and benchmarks can toggle it after this module loads.
+The transaction backend (core/backend.py) always asks for the kernel
+(``use_pallas=True``).  ``use_pallas=None`` callers (the language-model
+scaffolding) get the kernel on a TPU and the reference elsewhere, unless
+``REPRO_KERNELS`` ("pallas" | "ref") says otherwise — read per call, so
+tests can toggle it after this module loads.
 """
 from __future__ import annotations
 
@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from repro.core.claimword import inv_wave as _inv_wave
 from repro.kernels import ref
-from repro.kernels.claim_probe import claim_probe_fused_pallas
+from repro.kernels.wave_commit import claim_probe_fused_pallas
 from repro.kernels.claim_scatter import claim_scatter_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.iterate_validate import iterate_validate_pallas
@@ -59,7 +59,15 @@ def _use_pallas(use_pallas) -> bool:
 
 
 def _interp() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled on a TPU; interpret mode only on the CPU (the tests).  Any
+    other platform is an error, never a silent interpreter run."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(f"Pallas TPU kernels cannot run on {platform!r}: "
+                       "only a TPU (compiled) or the CPU (interpret mode)")
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:

@@ -1,22 +1,16 @@
-"""Sort-free routing-pack kernel (the distributed wave's send side).
+"""Routing-pack kernel (the distributed wave's send side).
 
-The sharded engine used to build its per-destination exchange buffers with
-an ``argsort`` over op owners plus ``bincount``/``cumsum`` offsets — the one
-per-wave sort left in the repo after the local wave went sort-free.  This
-kernel replaces it with a counting/offset scan: the grid walks destinations,
-each step matches the wave's owner vector against its destination id, a
-cumulative count gives every matching op its in-destination rank (the exact
-placement a *stable* argsort by owner would produce), and a rank-vs-slot
-one-hot select materializes the destination's fixed-capacity buffer row for
-every payload channel at once.  The whole wave ([M] int32 owners + [W, M]
-payloads) sits in VMEM, so like segment_count this is an all-pairs-style
-compare with no sort, no O(n_records) table, and an order-free result.
+The sharded engine builds per-destination fixed-capacity exchange buffers.
+The ranks are a counting scan (op ``i`` lands at slot ``pos[i]`` = the
+number of earlier ops bound for the same destination — the placement a
+*stable* argsort by owner would produce, without the sort); the kernel
+materializes the buffers: every output cell compares its flat slot id
+against the wave's slot row (128 cells x 128 ops at a time) and selects
+the one op, if any, that lands there, for every payload channel at once.
 
 Ops whose rank reaches the capacity are dropped (``took`` False — their
 lane aborts, counted by the caller); masked ops carry an out-of-range owner
-and match no destination.  Per-destination ``pos``/``took`` rows are
-reduced to per-op vectors by the wrapper (sum/any over destinations — each
-op matches at most one), bit-identical to ``ref.route_pack``.
+and match no destination.  Bit-identical to ``ref.route_pack``.
 """
 from __future__ import annotations
 
@@ -26,24 +20,38 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import rows as rw
 
-def _kernel(cap: int, fills, owner_ref, vals_ref, buf_ref, pos_ref,
-            took_ref):
-    d = pl.program_id(0)
-    own = owner_ref[0, :]                             # int32[M]
-    match = own == d
-    prefix = jnp.cumsum(match) - match                # rank within dest d
-    fit = match & (prefix < cap)
-    pos_ref[0, :] = jnp.where(match, prefix, 0).astype(jnp.int32)
-    took_ref[0, :] = fit
-    # One-hot (rank == slot) select: at most one op per buffer cell.
-    sel = fit[None, :] & (prefix[None, :]
-                          == jnp.arange(cap, dtype=jnp.int32)[:, None])
-    have = sel.any(axis=1)                            # bool[cap]
-    for w, fill in enumerate(fills):                  # W static channels
-        v = jnp.where(sel, vals_ref[w, :][None, :], 0).sum(axis=1)
-        buf_ref[w, 0, :] = jnp.where(have, v.astype(jnp.int32),
-                                     jnp.int32(fill))
+
+def _kernel(W, Mp, fills, slot_ref, vals_ref, buf_ref):
+    L = rw.LANES
+    cells = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    wcol = jax.lax.broadcasted_iota(jnp.int32, (L, W), 1)
+
+    def cell_chunk(c, _):
+        c0 = pl.multiple_of(c * L, L)
+
+        def op_chunk(o, acc):
+            o0 = pl.multiple_of(o * L, L)
+            sel = cells + c0 == slot_ref[:, pl.ds(o0, L)]
+            have = acc[0] | jnp.where(sel, 1, 0)
+            return (have,) + tuple(
+                a + jnp.where(sel, vals_ref[w:w + 1, pl.ds(o0, L)], 0)
+                for w, a in enumerate(acc[1:]))
+
+        zeros = jnp.zeros((L, L), jnp.int32)
+        acc = jax.lax.fori_loop(0, Mp // L, op_chunk,
+                                (zeros,) * (W + 1))
+        have = acc[0].max(axis=1, keepdims=True) != 0
+        tile = jnp.zeros((L, W), jnp.int32)
+        for w in range(W):
+            v = jnp.where(have, acc[1 + w].sum(axis=1, keepdims=True),
+                          fills[w])
+            tile = jnp.where(wcol == w, v, tile)
+        buf_ref[pl.ds(c0, L), :] = tile
+        return 0
+
+    jax.lax.fori_loop(0, buf_ref.shape[0] // L, cell_chunk, 0)
 
 
 def route_pack_pallas(owner: jax.Array, vals: jax.Array, n_dest: int,
@@ -51,22 +59,21 @@ def route_pack_pallas(owner: jax.Array, vals: jax.Array, n_dest: int,
                       ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """(buf [W, n_dest, cap], pos [M], took [M]) — see ref.route_pack."""
     W, M = vals.shape
-    out = pl.pallas_call(
-        functools.partial(_kernel, cap, tuple(fills)),
-        grid=(n_dest,),
-        in_specs=[
-            pl.BlockSpec((1, M), lambda d: (0, 0)),       # owner (whole wave)
-            pl.BlockSpec((W, M), lambda d: (0, 0)),       # payload channels
-        ],
-        out_specs=(
-            pl.BlockSpec((W, 1, cap), lambda d: (0, d, 0)),
-            pl.BlockSpec((1, M), lambda d: (d, 0)),
-            pl.BlockSpec((1, M), lambda d: (d, 0)),
-        ),
-        out_shape=(jax.ShapeDtypeStruct((W, n_dest, cap), jnp.int32),
-                   jax.ShapeDtypeStruct((n_dest, M), jnp.int32),
-                   jax.ShapeDtypeStruct((n_dest, M), jnp.bool_)),
+    d = jnp.arange(n_dest, dtype=jnp.int32)[:, None]
+    match = owner[None, :] == d                        # [n_dest, M]
+    prefix = jnp.cumsum(match, axis=1) - match         # rank within dest
+    pos = jnp.where(match, prefix, 0).sum(axis=0).astype(jnp.int32)
+    took = (match & (prefix < cap)).any(axis=0)
+    slot = jnp.where(took, owner * cap + pos, -1)
+    L = rw.LANES
+    Mp = -(-M // L) * L
+    C = n_dest * cap
+    Cp = -(-C // L) * L
+    buf = pl.pallas_call(
+        functools.partial(_kernel, W, Mp, tuple(int(f) for f in fills)),
+        out_shape=jax.ShapeDtypeStruct((Cp, W), jnp.int32),
         interpret=interpret,
-    )(owner.reshape(1, M), vals)
-    buf, pos_rows, took_rows = out
-    return buf, pos_rows.sum(axis=0).astype(jnp.int32), took_rows.any(axis=0)
+        name="route_pack",
+    )(jnp.pad(slot, (0, Mp - M), constant_values=-1).reshape(1, Mp),
+      jnp.pad(vals.astype(jnp.int32), ((0, 0), (0, Mp - M))))
+    return buf[:C].T.reshape(W, n_dest, cap), pos, took

@@ -3,15 +3,14 @@
 TicToc's cost model needs, per op, how many ops of the SAME WAVE hit the
 same (record, group) cell — the rts-extension CAS chain length and the
 commit-ts install chain (cc/tictoc.py).  The jnp path counts segments with
-an XLA sort + two searchsorted passes; this kernel closes that last XLA hop
-on the pallas TicToc path (ROADMAP item) with a direct all-pairs compare:
-the wave's op set is tiny ([T, K] int32s fit in VMEM whole), so each grid
-step loads one lane's ops plus the full wave and the VPU reduces the
-[T*K, K] equality matrix — no sort, no O(n_records) table, and the count is
-an order-free sum, bit-identical to the sorted formulation.
+an XLA sort + two searchsorted passes; this kernel does a direct all-pairs
+compare instead: the wave's cell ids sit in VMEM as one lane-dense row, and
+each grid step compares a block of ops (one per sublane) against the whole
+wave 128 lanes at a time, summing matches — no sort, no O(n_records)
+table, and the count is an order-free sum, bit-identical to the sorted
+formulation.
 
-Masked ops take a sentinel cell id and masked columns are zeroed, matching
-ref.segment_count exactly.
+Masked ops take a sentinel cell id and report 0, matching ref.segment_count.
 """
 from __future__ import annotations
 
@@ -20,34 +19,41 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import rows as rw
 
 
-def _kernel(G: int, keys_ref, grp_ref, msk_ref, mykeys_ref, mygrp_ref,
-            mymsk_ref, out_ref):
-    sent = jnp.int32(0x7FFFFFFF)
-    all_cell = jnp.where(msk_ref[...], keys_ref[...] * G + grp_ref[...],
-                         sent).reshape(-1)                # int32[T*K]
-    my_cell = jnp.where(mymsk_ref[0, :], mykeys_ref[0, :] * G
-                        + mygrp_ref[0, :], sent)          # int32[K]
-    eq = (all_cell[:, None] == my_cell[None, :]) & msk_ref[...].reshape(-1)[
-        :, None]                                          # [T*K, K]
-    cnt = eq.sum(axis=0)
-    out_ref[0, :] = jnp.where(mymsk_ref[0, :], cnt.astype(jnp.float32), 0.0)
+def _kernel(LBK, TKp, cell_b, wave, out_b):
+    ident = rw.eye(LBK)
+    cell_c = rw.to_col(cell_b[...], ident)
+
+    def chunk(c, acc):
+        off = pl.multiple_of(c * rw.LANES, rw.LANES)
+        return acc + (cell_c == wave[:, pl.ds(off, rw.LANES)]).astype(
+            jnp.int32)
+
+    acc = jax.lax.fori_loop(0, TKp // rw.LANES, chunk,
+                            jnp.zeros((LBK, rw.LANES), jnp.int32))
+    cnt = jnp.where(cell_c != rw.SENT, acc.sum(axis=1, keepdims=True), 0)
+    out_b[...] = rw.to_row(cnt, ident)
 
 
 def segment_count_pallas(keys: jax.Array, groups: jax.Array, G: int,
-                         mask: jax.Array,
+                         mask: jax.Array, lane_block: int = 0,
                          interpret: bool = False) -> jax.Array:
     """float32[T, K] same-cell op counts — see ref.segment_count."""
     T, K = keys.shape
-    full = pl.BlockSpec((T, K), lambda t: (0, 0))
-    mine = pl.BlockSpec((1, K), lambda t: (t, 0))
-    return pl.pallas_call(
-        functools.partial(_kernel, G),
-        grid=(T,),
-        in_specs=[full, full, full, mine, mine, mine],
-        out_specs=pl.BlockSpec((1, K), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((T, K), jnp.float32),
+    LBK, Tp = rw.blocking(keys, lane_block)
+    TKp = Tp * K
+    cell = rw.op_rows(jnp.where(mask, keys * G + groups, rw.SENT), Tp,
+                      fill=rw.SENT)
+    out = pl.pallas_call(
+        functools.partial(_kernel, LBK, TKp),
+        grid=(TKp // LBK,),
+        in_specs=[rw.blk_spec(LBK), rw.full_spec((1, TKp))],
+        out_specs=rw.blk_spec(LBK),
+        out_shape=jax.ShapeDtypeStruct((1, TKp), jnp.int32),
         interpret=interpret,
-    )(keys, groups, mask, keys, groups, mask)
+        name="segment_count",
+    )(cell, cell)
+    return rw.from_rows(out, T, K).astype(jnp.float32)

@@ -3,62 +3,41 @@
 TicToc reads two timestamps per op — the cell's write timestamp and read
 timestamp — before computing its commit_ts.  On the paper's CPU platform this
 is the same pointer chase as OCC validation; the TPU-native formulation is the
-same scalar-prefetch DMA as kernels/occ_validate.py: op keys are prefetched
-into SMEM, each grid step DMAs one timestamp-table row HBM->VMEM (the
-BlockSpec index_map reads the key), and the VPU selects the observation width.
+same block row-DMA gather as kernels/occ_validate.py (``rows.gather_call``),
+and the VPU selects the observation width.
 
 Granularity is the observation width (DESIGN.md sections 2 and 5): fine reads
-the op's own group column, coarse reads the row *max* — one timestamp per
+the op's own group word, coarse reads the record's *max* — one timestamp per
 record means any group's modification constrains the whole row.  The row is
 already in VMEM either way, so the coarse reduce is free: the DMA cost is
 identical for both granularities.
 
-Masked ops (key < 0) clamp their DMA to row 0 and are forced to 0 in the
-output — the same fill value the jnp gather path uses.
+Masked ops (key < 0) are forced to 0 in the output — the same fill value the
+jnp gather path uses.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-
-def _kernel(fine: bool, G: int, keys_ref, grp_ref, row_ref, out_ref):
-    row = row_ref[0, :]                                   # uint32[G]
-    if fine:
-        g = grp_ref[0, 0]
-        sel = jnp.arange(G, dtype=jnp.int32) == g
-        ts = jnp.where(sel, row, jnp.uint32(0)).max()
-    else:
-        ts = row.max()
-    t, k = pl.program_id(0), pl.program_id(1)
-    live = keys_ref[t, k] >= 0
-    out_ref[0, 0] = jnp.where(live, ts, jnp.uint32(0))
+from repro.kernels import rows as rw
 
 
 def ts_gather_pallas(table: jax.Array, keys: jax.Array, groups: jax.Array,
-                     fine: bool, interpret: bool = False) -> jax.Array:
+                     fine: bool, lane_block: int = 0,
+                     interpret: bool = False) -> jax.Array:
     """Per-op timestamp observation uint32[T, K] — see ref.ts_gather."""
-    T, K = keys.shape
-    G = table.shape[1]
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # keys drive the index_maps
-        grid=(T, K),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda t, k, keys: (t, k)),      # groups
-            # One timestamp-table row per op, DMA'd by prefetched key.
-            pl.BlockSpec((1, G),
-                         lambda t, k, keys: (jnp.maximum(keys[t, k], 0), 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda t, k, keys: (t, k)),
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, fine, G),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, K), jnp.uint32),
-        interpret=interpret,
-    )(keys, groups, table)
+    def compute(words, scalars, cols):
+        key_c, grp_c = cols[1], cols[2]
+        if fine:
+            ts = rw.group_word(words, grp_c)
+        else:
+            ts = words[0]
+            for w in words[1:]:
+                ts = rw.umax(ts, w)
+        return [jnp.where(key_c >= 0, ts, 0)]
+
+    (ts,) = rw.gather_call(compute, table, keys, [groups], [], 1,
+                           lane_block, interpret, "ts_gather")
+    return jax.lax.bitcast_convert_type(ts, jnp.uint32)

@@ -1,14 +1,14 @@
 """Scatter-max timestamp-install kernel (TicToc's wts/rts advance).
 
 TicToc installs commit timestamps monotonically: wts/rts of each written
-(record, group) cell only ever move up (`table.at[...].max` on the jnp
-backend).  This kernel is the aliased-output formulation, extending
-kernels/occ_commit.py's pattern: the timestamp table is both input and output
-(input_output_aliases), the sequential TPU grid walks the wave's ops, and each
-step DMAs the op's row, maxes in the candidate value, and writes it back.
-Because max is commutative and idempotent, duplicate (record, group) cells in
-one wave land on the same result in any visit order — which is what makes the
-kernel bit-identical to the XLA scatter-max.
+(record, group) cell only ever move up (``table.at[...].max`` on the jnp
+backend).  This kernel is the block read-modify-write of
+``rows.scatter_call``: the packed timestamp table is both input and output
+(input_output_aliases), each block fetches its ops' rows, folds every
+op's candidate in with an unsigned max, and writes the final rows back.
+Because max is commutative and idempotent, duplicate (record, group)
+cells in one wave land on the same result in any order — which is what
+makes the kernel bit-identical to the XLA scatter-max.
 
 ``whole_row=True`` installs the value across *every* group of the record —
 coarse-granularity rts extension raises the whole row's read horizon (one
@@ -16,55 +16,18 @@ timestamp per record; see cc/tictoc.py).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-
-def _kernel(whole_row: bool, keys_ref, grp_ref, val_ref, do_ref, row_ref,
-            out_ref):
-    # Accumulate through the *output* ref (see occ_commit.py): the aliased
-    # out buffer holds the current table and sequential grid steps revisiting
-    # a row read back their predecessors' installs.
-    del row_ref
-    G = out_ref.shape[-1]
-    if whole_row:
-        sel = jnp.ones((G,), jnp.bool_)
-    else:
-        g = grp_ref[0, 0]
-        sel = jnp.arange(G, dtype=jnp.int32) == g
-    cand = jnp.where(sel & do_ref[0, 0], val_ref[0, 0], jnp.uint32(0))
-    out_ref[0, :] = jnp.maximum(out_ref[0, :], cand)
+from repro.kernels import rows as rw
 
 
 def ts_install_max_pallas(table: jax.Array, keys: jax.Array,
                           groups: jax.Array, vals: jax.Array, do: jax.Array,
-                          whole_row: bool = False,
+                          whole_row: bool = False, lane_block: int = 0,
                           interpret: bool = False) -> jax.Array:
     """table' with table[k, g] = max(table[k, g], vals) per masked op — see
     ref.ts_install_max."""
-    T, K = keys.shape
-    G = table.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(T, K),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda t, k, keys: (t, k)),      # groups
-            pl.BlockSpec((1, 1), lambda t, k, keys: (t, k)),      # vals
-            pl.BlockSpec((1, 1), lambda t, k, keys: (t, k)),      # do
-            pl.BlockSpec((1, G),
-                         lambda t, k, keys: (jnp.maximum(keys[t, k], 0), 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, G), lambda t, k, keys: (jnp.maximum(keys[t, k], 0), 0)),
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, whole_row),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
-        input_output_aliases={4: 0},  # table is operand 4 counting prefetch
-        interpret=interpret,
-    )(keys, groups, vals.astype(jnp.uint32), do & (keys >= 0), table)
+    return rw.scatter_call(rw.umax, table, keys, groups,
+                           vals.astype(jnp.uint32), do, whole_row,
+                           lane_block, interpret, "ts_install_max")

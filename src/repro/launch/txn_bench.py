@@ -39,19 +39,40 @@ def _make_workload(workload: str, *, scale: float = 1.0,
                              scan_frac=scan_frac, scan_len=scan_len or 8)
 
 
+def _device_fields() -> dict:
+    """The device every row ran on, as JAX reports it."""
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}
+
+
+def _model_kind() -> str:
+    """Device kind the analytic roofline columns are stated for: the TPU
+    the run is on (a kind missing from analysis/peaks.py raises), or a
+    v5e for runs on the CPU, where no device is measured."""
+    import jax
+    from repro.analysis import peaks
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        peaks.peaks_for(dev.device_kind)
+        return dev.device_kind
+    return peaks.V5E
+
+
 def _cost_fields(cc_name: str, lanes: int, granularity: int, slots: int,
                  n_groups: int, mv_depth: int, max_extent: int = 1,
                  bucket_size: int = 8) -> dict:
     """Per-op roofline cost-model columns (analysis/txn_cost.py): analytic
     bytes/flops per transaction attempt and the mechanism's fraction of
-    the default chip's roofline.  Closed-form in the wave shape, so the
+    the roofline of ``_model_kind()``.  Closed-form in the wave shape, so the
     fields are backend-INDEPENDENT (CI's jnp-vs-pallas CLI parity diff
     relies on that)."""
     from repro.analysis import txn_cost as tc
     shape = tc.WaveShape(lanes=lanes, slots=slots, n_groups=n_groups,
                          granularity=granularity, mv_depth=mv_depth,
                          max_extent=max_extent, bucket_size=bucket_size)
-    cost = tc.txn_cost(cc_name, shape)
+    cost = tc.txn_cost(cc_name, shape, chip=_model_kind())
     fields = {
         "bytes_per_txn": round(cost["bytes_per_txn"], 1),
         "flops_per_txn": round(cost["flops_per_txn"], 1),
@@ -91,6 +112,7 @@ def _row(workload: str, cc_name: str, p, wall_s: float,
         "ext_events": p.ext_events,
         "wall_s": round(wall_s, 2),
         "backend": backend,
+        **_device_fields(),
         # Which backend-surface ops this mechanism actually routed through
         # Pallas kernels vs XLA — makes BENCH_*.json trajectories
         # attributable to an execution engine (DESIGN.md section 5).
@@ -227,6 +249,7 @@ def run_one(workload: str, cc_name: str, gran: int, lanes: int, waves: int,
         "ext_events": res.ext_events,
         "wall_s": round(wall, 2),
         "backend": backend,
+        **_device_fields(),
         "kernel_ops": kernel_coverage(backend, t.CC_IDS[cc_name]),
         "max_extent": cfg.max_extent,
     }
@@ -313,6 +336,8 @@ def main(argv=None):
                          "REPRO_TRACE=1 (or =<path>) enables the same "
                          "without a flag")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     ycsb_flags = (args.write_frac, args.ro_frac, args.theta)
     if args.workload == "tpcc" and any(v is not None for v in ycsb_flags):

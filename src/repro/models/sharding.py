@@ -20,6 +20,15 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
+def auto_mesh(mesh):
+    """``mesh`` with plain auto axes (``jax.make_mesh`` makes explicit
+    ones, which put shardings into array types and make the model's
+    sharded contractions ambiguous)."""
+    if mesh is None:
+        return None
+    return Mesh(mesh.devices, mesh.axis_names)
+
+
 def batch_axes(mesh) -> tuple:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 

@@ -53,6 +53,7 @@ def loss_fn(params, cfg, ctx, batch, constrain):
 
 # ------------------------------------------------------------------- train
 def build_train_step(cfg: ModelConfig, mesh, optimizer: AdamW):
+    mesh = shd.auto_mesh(mesh)
     constrain = shd.make_constrain(cfg, mesh)
     ctx = ModelCtx(tp=shd.tp_width(mesh), n_groups=shd.n_batch_shards(mesh),
                    mode="train", mesh=mesh)
@@ -111,6 +112,7 @@ def build_train_step(cfg: ModelConfig, mesh, optimizer: AdamW):
 
 # ----------------------------------------------------------------- serving
 def build_prefill_step(cfg: ModelConfig, mesh, s_cache: int):
+    mesh = shd.auto_mesh(mesh)
     constrain = shd.make_constrain(cfg, mesh)
     tp = shd.tp_width(mesh)
     ctx = ModelCtx(tp=tp, n_groups=shd.n_batch_shards(mesh), mode="prefill",
@@ -128,6 +130,7 @@ def build_prefill_step(cfg: ModelConfig, mesh, s_cache: int):
 
 
 def build_decode_step(cfg: ModelConfig, mesh):
+    mesh = shd.auto_mesh(mesh)
     constrain = shd.make_constrain(cfg, mesh)
     tp = shd.tp_width(mesh)
     ng = shd.n_batch_shards(mesh)

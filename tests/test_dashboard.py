@@ -105,7 +105,7 @@ def test_render_mech_cost_and_cause_columns():
                            "lock_wound": 0, "ww": 0, "read_val": 56},
              bytes_per_txn=512.0, flops_per_txn=128.0,
              roofline_frac=0.00104, roofline_bound="memory",
-             roofline_chip="tpu_v5e")
+             roofline_chip="TPU v5 lite")
     md = render_markdown([r], [])
     assert "| ycsb | occ | fine | pallas | 25.500 | 64 | 20.00% " \
            "| read_val:56 | — | 512 | 128 | 0.10% (memory) | — | — " \

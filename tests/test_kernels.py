@@ -342,6 +342,43 @@ def test_precondition_checks_jit_free_and_env_gated(monkeypatch):
                           True)
 
 
+@pytest.mark.parametrize("shape,dtype", [((33, 1), np.uint32),
+                                         ((1500, 2), np.uint32),
+                                         ((2048, 4, 2), np.uint32),
+                                         ((700,), np.int32)])
+def test_packed_rows_roundtrip(shape, dtype):
+    """rows.pack lays cell (k, o, g) at lane k & 127 of row
+    o * Nb * G + (k >> 7) * G + g, and unpack inverts it exactly."""
+    from repro.kernels import rows
+    x = np.random.default_rng(1).integers(0, 2 ** 31, shape).astype(dtype)
+    p = np.asarray(rows.pack(jnp.asarray(x)))
+    assert p.shape[1] == 128 and p.shape[0] % 8 == 0
+    flat = x.reshape(shape[0], -1)
+    G = shape[2] if len(shape) == 3 else (shape[1] if len(shape) == 2
+                                            else 1)
+    Nb = p.shape[0] // (flat.shape[1])
+    offs = rows.row_offsets(shape)
+    for k in (0, shape[0] // 2, shape[0] - 1):
+        for r, off in enumerate(offs):
+            assert p[(k >> 7) * G + off, k & 127].view(dtype) == flat[k, r]
+    assert Nb * 128 >= shape[0]
+    back = rows.unpack(jnp.asarray(p), jnp.asarray(x))
+    assert back.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(back), x)
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    """Kernels compile on a TPU, interpret on the CPU, and refuse any other
+    platform instead of quietly interpreting there."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._interp() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interp() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="cannot run on 'gpu'"):
+        ops._interp()
+
+
 def test_repro_kernels_env_resolved_per_call(monkeypatch):
     """REPRO_KERNELS must be read per call, not frozen at import time."""
     monkeypatch.setenv("REPRO_KERNELS", "pallas")

@@ -20,24 +20,27 @@ def test_shape_bytes():
 
 def test_collective_parser_counts_loop_trips():
     """Compile a scan whose body does a per-iteration psum on 8 host devices
-    (subprocess: device count must be set before jax init)."""
+    (subprocess: device count must be set before jax init).  The result
+    is asked for column-sharded, the layout the partitioner keeps the scan
+    carry in: a replicated result would add one real all-gather after the
+    loop, which the parser (rightly) counts too."""
     prog = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import jax, jax.numpy as jnp, sys
+        import jax, jax.numpy as jnp, numpy as np, sys
         sys.path.insert(0, "src")
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         from repro.analysis.roofline import collective_bytes_from_hlo
-        from repro.core.compat import shard_map
 
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = Mesh(np.array(jax.devices()), ("model",))
         def f(x, w):
             def body(c, _):
                 def mm(cc, ww):
                     return jax.lax.psum(cc @ ww, "model")
-                y = shard_map(mm, mesh=mesh,
-                              in_specs=(P(None, "model"), P("model", None)),
-                              out_specs=P())(c, w)
+                y = jax.shard_map(mm, mesh=mesh,
+                                  in_specs=(P(None, "model"),
+                                            P("model", None)),
+                                  out_specs=P(), check_vma=False)(c, w)
                 return y, None
             return jax.lax.scan(body, x, None, length=5)[0]
         x = jax.ShapeDtypeStruct((128, 512), jnp.float32,
@@ -45,7 +48,8 @@ def test_collective_parser_counts_loop_trips():
         w = jax.ShapeDtypeStruct((512, 512), jnp.float32,
                                  sharding=NamedSharding(mesh, P("model",
                                                                 None)))
-        hlo = jax.jit(f).lower(x, w).compile().as_text()
+        out = NamedSharding(mesh, P(None, "model"))
+        hlo = jax.jit(f, out_shardings=out).lower(x, w).compile().as_text()
         b = collective_bytes_from_hlo(hlo)
         assert b == 5 * 128 * 512 * 4, b
         print("PARSER_OK", b)

@@ -118,7 +118,8 @@ def test_txn_bench_grid_schema():
             "backend", "kernel_ops", "abort_causes", "bytes_per_txn",
             "flops_per_txn", "roofline_frac", "roofline_bound",
             "roofline_chip", "launches_per_wave", "dma_rows_per_wave",
-            "dma_rows_per_wave_unfused", "max_extent"}
+            "dma_rows_per_wave_unfused", "max_extent", "platform",
+            "device_kind", "device_count"}
     for r in rows:
         assert set(r) == want
         assert r["backend"] == "jnp"

@@ -2,6 +2,7 @@
 against the backend attribution tables (and the tictoc source), the
 granularity switch visible as a byte difference, and the memory-bound
 verdict on every chip in the shared peaks table."""
+import pytest
 import re
 
 import repro.analysis.peaks as peaks
@@ -179,7 +180,8 @@ def test_roofline_reexports_shared_peaks():
     assert roofline.PEAK_FLOPS is peaks.PEAK_FLOPS
     assert roofline.HBM_BW is peaks.HBM_BW
     assert roofline.LINK_BW is peaks.LINK_BW
-    d = peaks.HW_PEAKS[peaks.DEFAULT_CHIP]
+    d = peaks.HW_PEAKS[peaks.V5E]
     assert peaks.PEAK_FLOPS == d["peak_flops"]
-    assert peaks.ridge(peaks.DEFAULT_CHIP) == (d["peak_flops"]
-                                               / d["hbm_bw"])
+    assert peaks.ridge(peaks.V5E) == (d["peak_flops"] / d["hbm_bw"])
+    with pytest.raises(ValueError, match="no peak table entry"):
+        peaks.ridge("cpu")

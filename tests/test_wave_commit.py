@@ -22,7 +22,7 @@ from repro.core.claimword import EMPTY_WORD, NO_PRIO
 from repro.core.engine import run, sweep
 from repro.core.types import EngineConfig, TxnBatch, store_init
 from repro.kernels import ops, ref
-from repro.kernels.wave_commit import pick_lane_block
+from repro.kernels.rows import pick_lane_block
 from repro.workloads import YCSBWorkload
 
 RNG = np.random.default_rng(7)
@@ -146,15 +146,19 @@ def test_wave_commit_monotone_tag_precondition(monkeypatch):
 
 
 def test_pick_lane_block():
-    """Lane-block selection: overrides snap DOWN to a divisor of T (so the
-    grid tiles exactly), auto widths shrink as the table row widens, and
-    the result always divides T."""
-    assert pick_lane_block(8, 4, 2, override=3) == 2     # snap 3 -> 2
-    assert pick_lane_block(8, 4, 2, override=64) == 8    # cap at T
-    assert pick_lane_block(8, 4, 512) == 1               # wide row -> 1 lane
-    for T in (6, 8, 64, 96):
-        for g in (1, 2, 64, 512):
-            assert T % pick_lane_block(T, 16, g) == 0
+    """Lane-block selection: a block of LB * K ops fills whole 128-lane
+    rows (the chip's tile), the auto width is the least such LB, and an
+    override rounds UP to the next such multiple (the wave is padded to
+    whole blocks with masked lanes)."""
+    assert pick_lane_block(16) == 8                       # 8 x 16 = 128
+    assert pick_lane_block(64) == 2
+    assert pick_lane_block(4, override=3) == 32           # 32 x 4 = 128
+    assert pick_lane_block(16, override=9) == 16          # next multiple
+    assert pick_lane_block(3) == 128                      # lcm(3, 128) / 3
+    for K in (1, 3, 4, 6, 16, 64, 96):
+        for override in (0, 1, 2, 7, 64):
+            lb = pick_lane_block(K, override)
+            assert (lb * K) % 128 == 0 and lb >= override
     with pytest.raises(ValueError):
         EngineConfig(cc=t.CC_OCC, lanes=8, slots=4, n_records=64,
                      n_groups=2, n_cols=0, n_txn_types=1, lane_block=-1)
@@ -266,7 +270,7 @@ def _pallas_launches(fn, *args):
     def walk(jx, out):
         for eqn in jx.eqns:
             if eqn.primitive.name == "pallas_call":
-                out.append(str(eqn.params.get("name_and_src_info")))
+                out.append(str(eqn.params.get("name")))
             for v in eqn.params.values():
                 for x in (v if isinstance(v, (list, tuple)) else (v,)):
                     if hasattr(x, "jaxpr"):
@@ -301,7 +305,7 @@ def test_fused_wave_single_launch_guard(cc):
         return mod.wave_validate(s, b, p, jnp.uint32(1), cfg)
 
     names = _pallas_launches(fused, store, batch, prio)
-    wc = [n for n in names if "_wave_commit_kernel" in n]
+    wc = [n for n in names if n == "wave_commit"]
     assert len(wc) == 1, names
     assert not any("claim_probe" in n or "occ_commit" in n
                    for n in names), names
@@ -312,7 +316,7 @@ def test_fused_wave_single_launch_guard(cc):
         return mod.wave_validate(s, b, p, jnp.uint32(1), ucfg)
 
     unames = _pallas_launches(unfused, store, batch, prio)
-    assert not any("_wave_commit_kernel" in n for n in unames), unames
+    assert "wave_commit" not in unames, unames
     assert any("claim_probe" in n for n in unames), unames
 
 
