@@ -38,16 +38,12 @@ def sweep(workload: str, *, ccs=None, lanes=None, grans=(0, 1), waves=300,
     grid_kw = dict(scale=scale, n_keys=n_keys, seed=seed, backend=backend,
                    **wl_kw)
     if warm:
-        ret, dt = warm_then_time(run_grid, *grid_args, **grid_kw)
-        rows = ret[0] if isinstance(ret, tuple) else ret
+        rows, dt = warm_then_time(run_grid, *grid_args, **grid_kw)
         wall = round(dt / max(len(rows), 1), 4)
         for r in rows:
             r["wall_s"] = wall
     else:
-        ret = run_grid(*grid_args, **grid_kw)
-    # return_points=True (the trace exporters) makes run_grid return
-    # (rows, SweepPoints); plain callers get the row list as before.
-    rows = ret[0] if isinstance(ret, tuple) else ret
+        rows = run_grid(*grid_args, **grid_kw)
     if not quiet:
         for r in rows:
             line = (f"  {workload} {r['cc']:9s} "
@@ -59,7 +55,7 @@ def sweep(workload: str, *, ccs=None, lanes=None, grans=(0, 1), waves=300,
                 line += (f"  goodput={r['goodput']:8.3f}  "
                          f"p99ttc={max(r['p99_ttc_waves']):g}w")
             print(line)
-    return ret
+    return rows
 
 
 def save_rows(rows, path):

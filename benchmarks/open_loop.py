@@ -32,28 +32,13 @@ def main(argv=None):
     ap.add_argument("--max-incarnations", type=int, default=8)
     ap.add_argument("--backend", choices=("jnp", "pallas"), default="jnp")
     ap.add_argument("--json", default="reports/open_loop.json")
-    ap.add_argument("--trace", nargs="?", const="reports/open_loop_trace"
-                    ".json", default=None, metavar="PATH",
-                    help="export ONE combined Chrome-trace timeline across "
-                         "all offered loads (analysis/trace.py; rows are "
-                         "labeled cc/gran/rate) — REPRO_TRACE=1 also "
-                         "enables it")
     args = ap.parse_args(argv)
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
 
-    import os
-    trace_path = args.trace
-    if trace_path is None:
-        env = os.environ.get("REPRO_TRACE", "")
-        if env and env != "0":
-            trace_path = (env if env not in ("1", "true")
-                          else "reports/open_loop_trace.json")
-
     T = args.lanes
     rates = args.rates or [0.25 * T, 0.5 * T, 0.75 * T, 1.0 * T]
     rows = []
-    traced = []
     for rate in rates:
         # One jitted sweep per offered load (the arrival rate is part of
         # the compiled scan); occ + mvcc at both granularities per sweep.
@@ -61,12 +46,7 @@ def main(argv=None):
                     waves=args.waves, n_keys=args.n_keys,
                     backend=args.backend, quiet=True, warm=True,
                     arrival_rate=rate, queue_cap=4 * T,
-                    max_incarnations=args.max_incarnations,
-                    per_wave=bool(trace_path),
-                    return_points=bool(trace_path))
-        if trace_path:
-            got, points = got
-            traced += [(rate, p) for p in points]
+                    max_incarnations=args.max_incarnations)
         for r in got:
             r["arrival_rate"] = rate
         rows += got
@@ -78,30 +58,6 @@ def main(argv=None):
                   f"p99={max(r['p99_ttc_waves']):3g} waves  "
                   f"dropped={r['inc_drops']}")
     save_rows(rows, args.json)
-    if trace_path:
-        # One combined timeline: every (offered load x cc x granularity)
-        # grid point is its own process row on the simulated-time axis.
-        from repro.core import types as t
-        from repro.analysis.trace import point_events, validate_chrome_trace
-        import json as _json
-        events, pid = [], 0
-        for rate, p in traced:
-            pid += 1
-            label = (f"{t.CC_NAMES.get(p.cc, p.cc)}/"
-                     f"{'fine' if p.granularity else 'coarse'}/"
-                     f"rate{rate:g}")
-            events += point_events(label, pid, p.per_wave_commits,
-                                   p.per_wave_aborts, p.per_wave_us,
-                                   p.per_wave_causes)
-        trace = {"traceEvents": events, "displayTimeUnit": "ms",
-                 "otherData": {"source": "repro open-loop wave trace",
-                               "time_axis": "simulated microseconds"}}
-        errs = validate_chrome_trace(trace)
-        assert not errs, errs
-        os.makedirs(os.path.dirname(trace_path) or ".", exist_ok=True)
-        with open(trace_path, "w") as f:
-            _json.dump(trace, f)
-        print(f"[saved] {trace_path} ({pid} trace rows)")
 
     # The headline ordering: at the highest offered load, fine granularity
     # sustains more goodput than coarse for both mechanisms.

@@ -451,11 +451,12 @@ def _make_exchange(cfg: DistConfig, mesh):
         steps = [((math.prod(dims),), 0, ax if len(ax) > 1 else ax[0])]
 
     def exchange(buf):
-        x = buf.reshape(steps[0][0] + buf.shape[1:])
-        for _, i, name in steps:
-            x = jax.lax.all_to_all(x, axis_name=name, split_axis=i,
-                                   concat_axis=i, tiled=True)
-        return x.reshape(buf.shape)
+        with jax.named_scope("repro:exchange"):
+            x = buf.reshape(steps[0][0] + buf.shape[1:])
+            for _, i, name in steps:
+                x = jax.lax.all_to_all(x, axis_name=name, split_axis=i,
+                                       concat_axis=i, tiled=True)
+            return x.reshape(buf.shape)
 
     return exchange
 
@@ -766,6 +767,7 @@ def _make_shard_body(cfg: DistConfig, mesh):
     return body
 
 
+@jax.named_scope("repro:account")
 def _closed_stats(commit, lane_dropped, has_write, dropped_op, cause):
     ro = ~has_write
     z = jnp.int32(0)
@@ -817,15 +819,18 @@ def _make_pipeline_step(cfg: DistConfig, mesh):
     def step(carry, x):
         tables, rb1, rb2, rb3, v_in, c_in, st1, st2 = carry
         keys, groups, kinds, prio, wave = x
-        tables = owner_install(tables, rb3, c_in, wave - jnp.uint32(3))
-        tables, v_words = owner_claim(tables, rb1, wave - jnp.uint32(1))
+        with jax.named_scope("repro:schedule"):
+            w_install, w_claim = wave - jnp.uint32(3), wave - jnp.uint32(1)
+        tables = owner_install(tables, rb3, c_in, w_install)
+        tables, v_words = owner_claim(tables, rb1, w_claim)
         commit, c_words, cause = sender_commit(st2, v_in)
         out, st0 = route(keys, groups, kinds, prio)
-        arrived = exchange(jnp.concatenate([out, v_words, c_words],
-                                           axis=-1))
-        r_out = arrived[:, :2 * cap]
-        v_nxt = arrived[:, 2 * cap:2 * cap + W]
-        c_nxt = arrived[:, 2 * cap + W:]
+        with jax.named_scope("repro:exchange"):
+            arrived = exchange(jnp.concatenate([out, v_words, c_words],
+                                               axis=-1))
+            r_out = arrived[:, :2 * cap]
+            v_nxt = arrived[:, 2 * cap:2 * cap + W]
+            c_nxt = arrived[:, 2 * cap + W:]
         stats = _closed_stats(commit, st2[4], st2[5], st2[6], cause)
         carry = (tables, r_out, rb1, rb2, v_nxt, c_nxt, st0, st1)
         return carry, (commit, stats)
@@ -1016,56 +1021,63 @@ def make_open_wave_fn(cfg: DistConfig, mesh):
                 (ek, eg, ei, ea, ec, ed))
             return tabs + (size, n_acc, n_ovf)
 
-        # --- arrivals: first n_arrive fresh lanes enter the ring --------
-        n_arr = jnp.minimum(n_arrive[0], T)
-        arr = jnp.arange(T, dtype=jnp.int32) < n_arr
-        ids = nid + jnp.arange(T, dtype=jnp.int32)
-        qk, qg, qi, qa, qc, qd, size, n_adm, n_ovf = enq(
-            head, size, arr, keys, groups, kinds,
-            jnp.full((T,), w, jnp.int32), jnp.zeros((T,), jnp.int32), ids)
+        with jax.named_scope("repro:schedule"):
+            # --- arrivals: first n_arrive fresh lanes enter the ring -----
+            n_arr = jnp.minimum(n_arrive[0], T)
+            arr = jnp.arange(T, dtype=jnp.int32) < n_arr
+            ids = nid + jnp.arange(T, dtype=jnp.int32)
+            qk, qg, qi, qa, qc, qd, size, n_adm, n_ovf = enq(
+                head, size, arr, keys, groups, kinds,
+                jnp.full((T,), w, jnp.int32), jnp.zeros((T,), jnp.int32),
+                ids)
 
-        # --- admit: fill the shard's T lanes FIFO -----------------------
-        take = jnp.minimum(size, T)
-        i = jnp.arange(T, dtype=jnp.int32)
-        got = i < take
-        pos = (head + i) % C
-        dk = jnp.where(got[:, None], qk[pos, :], -1)
-        dg = jnp.where(got[:, None], qg[pos, :], 0)
-        di = jnp.where(got[:, None], qi[pos, :], t.NOP)
-        admit_w = jnp.where(got, qa[pos], 0)
-        incarn = jnp.where(got, qc[pos], 0)
-        head, size = (head + take) % C, size - take
+            # --- admit: fill the shard's T lanes FIFO --------------------
+            take = jnp.minimum(size, T)
+            i = jnp.arange(T, dtype=jnp.int32)
+            got = i < take
+            pos = (head + i) % C
+            dk = jnp.where(got[:, None], qk[pos, :], -1)
+            dg = jnp.where(got[:, None], qg[pos, :], 0)
+            di = jnp.where(got[:, None], qi[pos, :], t.NOP)
+            admit_w = jnp.where(got, qa[pos], 0)
+            incarn = jnp.where(got, qc[pos], 0)
+            head, size = (head + take) % C, size - take
 
         # --- the routed wave on the admitted lanes ----------------------
         commit, tables, lane_dropped, has_write, dropped_op, cause = body(
             dk, dg, di, prio, tables, wave_idx)
-        commit = commit & got
-        aborted = got & ~commit
 
-        # --- retry incarnations / latency -------------------------------
-        retry = aborted & (incarn < cfg.max_incarnations)
-        inc_drop = aborted & ~retry
-        # A terminal abort leaves the system as an incarnation drop — that
-        # outcome outranks whatever validation verdict killed the attempt
-        # (CAUSE_INC_CAP is the lowest code), mirroring the local engine.
-        cause = jnp.where(inc_drop, jnp.int32(t.CAUSE_INC_CAP), cause)
-        # Arrivals enqueued before the dequeue freed these slots, so this
-        # can never overflow (n_re_ovf stays 0; the oracle asserts it via
-        # the exact counter reconciliation).
-        qk, qg, qi, qa, qc, qd, size, _, n_re_ovf = enq(
-            head, size, retry, dk, dg, di, admit_w, incarn + 1,
-            jnp.where(got, qd[pos], -1))
-        lat_hist = admission.record_ttc(lat_hist, w - admit_w + 1, commit)
+        # --- retry incarnations ------------------------------------------
+        with jax.named_scope("repro:schedule"):
+            commit = commit & got
+            aborted = got & ~commit
+            retry = aborted & (incarn < cfg.max_incarnations)
+            inc_drop = aborted & ~retry
+            # Arrivals enqueued before the dequeue freed these slots, so
+            # this can never overflow (n_re_ovf stays 0; the oracle
+            # asserts it via the exact counter reconciliation).
+            qk, qg, qi, qa, qc, qd, size, _, n_re_ovf = enq(
+                head, size, retry, dk, dg, di, admit_w, incarn + 1,
+                jnp.where(got, qd[pos], -1))
 
-        ro = ~has_write
-        head_stats = jnp.stack([
-            commit.sum(), aborted.sum(), lane_dropped.sum(),
-            dropped_op.sum(),
-            (commit & ro).sum(), (aborted & ro).sum(),
-            n_adm, n_ovf + n_re_ovf,
-            inc_drop.sum(), size]).astype(jnp.int32)
-        stats = jnp.concatenate([head_stats, t.cause_counts(cause,
-                                                            aborted)])
+        # --- latency and stats -------------------------------------------
+        with jax.named_scope("repro:account"):
+            # A terminal abort leaves the system as an incarnation drop —
+            # that outcome outranks whatever validation verdict killed the
+            # attempt (CAUSE_INC_CAP is the lowest code), mirroring the
+            # local engine.
+            cause = jnp.where(inc_drop, jnp.int32(t.CAUSE_INC_CAP), cause)
+            lat_hist = admission.record_ttc(lat_hist, w - admit_w + 1,
+                                            commit)
+            ro = ~has_write
+            head_stats = jnp.stack([
+                commit.sum(), aborted.sum(), lane_dropped.sum(),
+                dropped_op.sum(),
+                (commit & ro).sum(), (aborted & ro).sum(),
+                n_adm, n_ovf + n_re_ovf,
+                inc_drop.sum(), size]).astype(jnp.int32)
+            stats = jnp.concatenate([head_stats,
+                                     t.cause_counts(cause, aborted)])
         qstate = (qk, qg, qi, qa, qc, qd, head[None], size[None],
                   (nid + n_arr)[None], lat_hist)
         return commit, tables, qstate, stats
@@ -1107,74 +1119,84 @@ def _make_open_pipeline_step(cfg: DistConfig, mesh):
         keys, groups, kinds, prio, n_arrive, wave, live_w = x
 
         # --- owner phases: install wave w-3, claim wave w-1 -------------
-        tables = owner_install(tables, rb3, c_in, wave - jnp.uint32(3))
-        tables, v_words = owner_claim(tables, rb1, wave - jnp.uint32(1))
+        with jax.named_scope("repro:schedule"):
+            w_install, w_claim = wave - jnp.uint32(3), wave - jnp.uint32(1)
+        tables = owner_install(tables, rb3, c_in, w_install)
+        tables, v_words = owner_claim(tables, rb1, w_claim)
 
         # --- sender: commit wave w-2, ring bookkeeping -------------------
         commit, c_words, cause = sender_commit(st2, v_in)
         dk2, dg2, di2, admit2, inc2, got2, qid2, n_adm2, n_ovf2 = os2
-        commit = commit & got2
-        aborted = got2 & ~commit
-        retry = aborted & (inc2 < cfg.max_incarnations)
-        # Terminal aborts reclassify as CAUSE_INC_CAP like the synchronous
-        # wave; a retry the full ring rejects (n_re_ovf) KEEPS its
-        # validation cause — ring_enqueue exposes no per-lane overflow
-        # mask — so causes[CAUSE_INC_CAP] <= inc_drops at depth >= 2
-        # while the per-cause sum still equals aborts exactly.
-        cause = jnp.where(aborted & ~retry, jnp.int32(t.CAUSE_INC_CAP),
-                          cause)
-        (qk, qg, qi, qa, qc, qd), size, _, n_re_ovf = admission.ring_enqueue(
-            C, head, size, retry, (qk, qg, qi, qa, qc, qd),
-            (dk2, dg2, di2, admit2, inc2 + 1, qid2))
-        inc_drop = (aborted & ~retry).sum() + n_re_ovf
-        w2 = (wave.astype(jnp.int32) - 2)
-        lat_hist = admission.record_ttc(lat_hist, w2 - admit2 + 1, commit)
+        with jax.named_scope("repro:schedule"):
+            commit = commit & got2
+            aborted = got2 & ~commit
+            retry = aborted & (inc2 < cfg.max_incarnations)
+            (qk, qg, qi, qa, qc, qd), size, _, n_re_ovf = \
+                admission.ring_enqueue(
+                    C, head, size, retry, (qk, qg, qi, qa, qc, qd),
+                    (dk2, dg2, di2, admit2, inc2 + 1, qid2))
 
-        # --- arrivals for wave w -----------------------------------------
-        n_arr = jnp.where(live_w, jnp.minimum(n_arrive, T), 0)
-        arr = jnp.arange(T, dtype=jnp.int32) < n_arr
-        ids = nid + jnp.arange(T, dtype=jnp.int32)
-        (qk, qg, qi, qa, qc, qd), size, n_adm, n_ovf = admission.ring_enqueue(
-            C, head, size, arr, (qk, qg, qi, qa, qc, qd),
-            (keys, groups, kinds,
-             jnp.full((T,), wave.astype(jnp.int32), jnp.int32),
-             jnp.zeros((T,), jnp.int32), ids))
-        nid = nid + n_arr
+            # --- arrivals for wave w -------------------------------------
+            n_arr = jnp.where(live_w, jnp.minimum(n_arrive, T), 0)
+            arr = jnp.arange(T, dtype=jnp.int32) < n_arr
+            ids = nid + jnp.arange(T, dtype=jnp.int32)
+            (qk, qg, qi, qa, qc, qd), size, n_adm, n_ovf = \
+                admission.ring_enqueue(
+                    C, head, size, arr, (qk, qg, qi, qa, qc, qd),
+                    (keys, groups, kinds,
+                     jnp.full((T,), wave.astype(jnp.int32), jnp.int32),
+                     jnp.zeros((T,), jnp.int32), ids))
+            nid = nid + n_arr
 
-        # --- dequeue wave w's lanes (never on drain steps) ---------------
-        take = jnp.where(live_w, jnp.minimum(size, T), 0)
-        i = jnp.arange(T, dtype=jnp.int32)
-        got = i < take
-        pos = (head + i) % C
-        dk = jnp.where(got[:, None], qk[pos, :], -1)
-        dg = jnp.where(got[:, None], qg[pos, :], 0)
-        di = jnp.where(got[:, None], qi[pos, :], t.NOP)
-        admit_w = jnp.where(got, qa[pos], 0)
-        incarn = jnp.where(got, qc[pos], 0)
-        qid = jnp.where(got, qd[pos], -1)
-        head, size = (head + take) % C, size - take
+            # --- dequeue wave w's lanes (never on drain steps) -----------
+            take = jnp.where(live_w, jnp.minimum(size, T), 0)
+            i = jnp.arange(T, dtype=jnp.int32)
+            got = i < take
+            pos = (head + i) % C
+            dk = jnp.where(got[:, None], qk[pos, :], -1)
+            dg = jnp.where(got[:, None], qg[pos, :], 0)
+            di = jnp.where(got[:, None], qi[pos, :], t.NOP)
+            admit_w = jnp.where(got, qa[pos], 0)
+            incarn = jnp.where(got, qc[pos], 0)
+            qid = jnp.where(got, qd[pos], -1)
+            head, size = (head + take) % C, size - take
 
         # --- route wave w, ONE fused exchange ----------------------------
         out, st0 = route(dk, dg, di, prio)
-        arrived = exchange(jnp.concatenate([out, v_words, c_words],
-                                           axis=-1))
-        r_out = arrived[:, :2 * cap]
-        v_nxt = arrived[:, 2 * cap:2 * cap + W]
-        c_nxt = arrived[:, 2 * cap + W:]
+        with jax.named_scope("repro:exchange"):
+            arrived = exchange(jnp.concatenate([out, v_words, c_words],
+                                               axis=-1))
+            r_out = arrived[:, :2 * cap]
+            v_nxt = arrived[:, 2 * cap:2 * cap + W]
+            c_nxt = arrived[:, 2 * cap + W:]
 
-        # Every counter in the emitted row belongs to wave w-2 (the wave
-        # whose fate resolved this step): its admission counters rode the
-        # os carry from the step that enqueued it, so the runner's
-        # [2 : 2+n_waves] slice conserves exactly.  QUEUED stays a current
-        # occupancy snapshot (informational; the driver's queued_final
-        # reads the final qstate, not this column).
-        ro = ~st2[5]
-        head_stats = jnp.stack([
-            commit.sum(), aborted.sum(), st2[4].sum(), st2[6].sum(),
-            (commit & ro).sum(), (aborted & ro).sum(),
-            n_adm2, n_ovf2, inc_drop, size]).astype(jnp.int32)
-        stats = jnp.concatenate([head_stats, t.cause_counts(cause,
-                                                            aborted)])
+        with jax.named_scope("repro:account"):
+            # Terminal aborts reclassify as CAUSE_INC_CAP like the
+            # synchronous wave; a retry the full ring rejects (n_re_ovf)
+            # KEEPS its validation cause — ring_enqueue exposes no
+            # per-lane overflow mask — so causes[CAUSE_INC_CAP] <=
+            # inc_drops at depth >= 2 while the per-cause sum still equals
+            # aborts exactly.
+            cause = jnp.where(aborted & ~retry, jnp.int32(t.CAUSE_INC_CAP),
+                              cause)
+            inc_drop = (aborted & ~retry).sum() + n_re_ovf
+            w2 = (wave.astype(jnp.int32) - 2)
+            lat_hist = admission.record_ttc(lat_hist, w2 - admit2 + 1,
+                                            commit)
+            # Every counter in the emitted row belongs to wave w-2 (the
+            # wave whose fate resolved this step): its admission counters
+            # rode the os carry from the step that enqueued it, so the
+            # runner's [2 : 2+n_waves] slice conserves exactly.  QUEUED
+            # stays a current occupancy snapshot (informational; the
+            # driver's queued_final reads the final qstate, not this
+            # column).
+            ro = ~st2[5]
+            head_stats = jnp.stack([
+                commit.sum(), aborted.sum(), st2[4].sum(), st2[6].sum(),
+                (commit & ro).sum(), (aborted & ro).sum(),
+                n_adm2, n_ovf2, inc_drop, size]).astype(jnp.int32)
+            stats = jnp.concatenate([head_stats, t.cause_counts(cause,
+                                                                aborted)])
         os0 = (dk, dg, di, admit_w, incarn, got, qid, n_adm, n_ovf)
         carry = (tables, r_out, rb1, rb2, v_nxt, c_nxt, st0, st1, os0, os1,
                  qk, qg, qi, qa, qc, qd, head, size, nid, lat_hist)

@@ -236,87 +236,94 @@ def make_wave_step(cfg: EngineConfig, workload: Workload,
     T = cfg.lanes
 
     def wave_step(state: EngineState, _):
-        rng, rng_gen, rng_perm = jax.random.split(state.rng, 3)
         wave = state.wave
+        with jax.named_scope("repro:gen"):
+            rng, rng_gen, rng_perm = jax.random.split(state.rng, 3)
+            fresh, tails = workload.gen(rng_gen, wave, T,
+                                        state.store.ring_tails)
 
-        fresh, tails = workload.gen(rng_gen, wave, T, state.store.ring_tails)
-        # Lanes with an aborted transaction retry it; the rest draw fresh.
-        sel = state.pending_live
-        batch = jax.tree.map(
-            lambda p, f: jnp.where(
-                sel.reshape((T,) + (1,) * (p.ndim - 1)), p, f),
-            state.pending, fresh)
-        age = jnp.where(sel, state.age, 0)
-        if active is not None:
-            # Padding lanes run empty transactions: no ops => no claims, no
-            # conflicts, and the accounting below masks them out.
-            batch = dataclasses.replace(
-                batch,
-                op_key=jnp.where(active[:, None], batch.op_key, -1),
-                op_kind=jnp.where(active[:, None], batch.op_kind, t.NOP),
-                n_ops=jnp.where(active, batch.n_ops, 0))
+        with jax.named_scope("repro:schedule"):
+            # Lanes with an aborted transaction retry it; the rest draw fresh.
+            sel = state.pending_live
+            batch = jax.tree.map(
+                lambda p, f: jnp.where(
+                    sel.reshape((T,) + (1,) * (p.ndim - 1)), p, f),
+                state.pending, fresh)
+            age = jnp.where(sel, state.age, 0)
+            if active is not None:
+                # Padding lanes run empty transactions: no ops => no
+                # claims, no conflicts, and the accounting masks them out.
+                batch = dataclasses.replace(
+                    batch,
+                    op_key=jnp.where(active[:, None], batch.op_key, -1),
+                    op_kind=jnp.where(active[:, None], batch.op_kind,
+                                      t.NOP),
+                    n_ops=jnp.where(active, batch.n_ops, 0))
+            perm = jax.random.permutation(rng_perm, T).astype(jnp.uint32)
+            prio = claims.prio16(age, perm, use_age=(cfg.cc == t.CC_SWISS))
         store = dataclasses.replace(state.store, ring_tails=tails)
-
-        perm = jax.random.permutation(rng_perm, T).astype(jnp.uint32)
-        prio = claims.prio16(age, perm, use_age=(cfg.cc == t.CC_SWISS))
 
         with jax.named_scope("repro:validate"):
             store, res = validator(store, batch, prio, wave, cfg)
-        commit = res.commit
-
-        if cfg.track_values:
-            vals = apply_values(store.values, batch, commit, prio)
-            store = dataclasses.replace(store, values=vals)
+            commit = res.commit
+            if cfg.track_values:
+                vals = apply_values(store.values, batch, commit, prio)
+                store = dataclasses.replace(store, values=vals)
 
         # ---- cost model ----
         with jax.named_scope("repro:cost"):
             lane_dt, has_write = _lane_cost(cfg, batch, commit, res)
 
         # ---- metrics + retry bookkeeping ----
-        if active is None:
-            committed, aborted = commit, ~commit
-        else:
-            committed, aborted = commit & active, ~commit & active
-            lane_dt = jnp.where(active, lane_dt, 0.0)
-        causes_wave = t.cause_counts(res.lane_cause(), aborted)
-        if cfg.track_conflicts:
-            hits, peak = _conflict_histogram(
-                cfg, state.conflict_hits, state.conflict_peak, batch, res)
-        else:
-            hits, peak = state.conflict_hits, state.conflict_peak
-        commits_by_type = state.commits_by_type.at[batch.txn_type].add(
-            committed.astype(state.commits_by_type.dtype))
-        # Read-only lanes: the MV mechanisms' headline is that these never
-        # abort.  Padding lanes are empty and therefore "read-only", but
-        # committed/aborted already mask them out.
-        ro = ~has_write
-        new_state = EngineState(
-            rng=rng,
-            wave=wave + 1,
-            store=store,
-            pending=batch,
-            pending_live=aborted,
-            age=jnp.where(commit, 0, age + 1),
-            lane_time=state.lane_time + lane_dt,
-            commits=state.commits
-                    + committed.sum().astype(state.commits.dtype),
-            aborts=state.aborts + aborted.sum().astype(state.aborts.dtype),
-            commits_by_type=commits_by_type,
-            wasted_time=state.wasted_time
-                        + jnp.where(committed, 0.0, lane_dt).sum(),
-            ext_events=state.ext_events + res.ext_count,
-            ro_commits=state.ro_commits
-                       + (committed & ro).sum().astype(state.ro_commits.dtype),
-            ro_aborts=state.ro_aborts
-                      + (aborted & ro).sum().astype(state.ro_aborts.dtype),
-            abort_causes=state.abort_causes + causes_wave,
-            conflict_hits=hits,
-            conflict_peak=peak,
-            ol=state.ol,
-        )
-        ys = (committed.sum().astype(jnp.int32),
-              aborted.sum().astype(jnp.int32),
-              causes_wave, lane_dt.sum())
+        with jax.named_scope("repro:account"):
+            if active is None:
+                committed, aborted = commit, ~commit
+            else:
+                committed, aborted = commit & active, ~commit & active
+                lane_dt = jnp.where(active, lane_dt, 0.0)
+            causes_wave = t.cause_counts(res.lane_cause(), aborted)
+            if cfg.track_conflicts:
+                hits, peak = _conflict_histogram(
+                    cfg, state.conflict_hits, state.conflict_peak, batch,
+                    res)
+            else:
+                hits, peak = state.conflict_hits, state.conflict_peak
+            commits_by_type = state.commits_by_type.at[batch.txn_type].add(
+                committed.astype(state.commits_by_type.dtype))
+            # Read-only lanes: the MV mechanisms' headline is that these
+            # never abort.  Padding lanes are empty and therefore
+            # "read-only", but committed/aborted already mask them out.
+            ro = ~has_write
+            new_state = EngineState(
+                rng=rng,
+                wave=wave + 1,
+                store=store,
+                pending=batch,
+                pending_live=aborted,
+                age=jnp.where(commit, 0, age + 1),
+                lane_time=state.lane_time + lane_dt,
+                commits=state.commits
+                        + committed.sum().astype(state.commits.dtype),
+                aborts=state.aborts
+                       + aborted.sum().astype(state.aborts.dtype),
+                commits_by_type=commits_by_type,
+                wasted_time=state.wasted_time
+                            + jnp.where(committed, 0.0, lane_dt).sum(),
+                ext_events=state.ext_events + res.ext_count,
+                ro_commits=state.ro_commits
+                           + (committed & ro).sum().astype(
+                               state.ro_commits.dtype),
+                ro_aborts=state.ro_aborts
+                          + (aborted & ro).sum().astype(
+                              state.ro_aborts.dtype),
+                abort_causes=state.abort_causes + causes_wave,
+                conflict_hits=hits,
+                conflict_peak=peak,
+                ol=state.ol,
+            )
+            ys = (committed.sum().astype(jnp.int32),
+                  aborted.sum().astype(jnp.int32),
+                  causes_wave, lane_dt.sum())
         return new_state, ys
 
     return wave_step
@@ -349,116 +356,126 @@ def make_open_wave_step(cfg: EngineConfig, workload: Workload,
     n_active = T if active is None else active.sum().astype(jnp.int32)
 
     def wave_step(state: EngineState, _):
-        rng, rng_gen, rng_perm, rng_arr = jax.random.split(state.rng, 4)
         wave = state.wave
         ol = state.ol
 
         # ---- arrivals: the wave's fresh transactions, Poisson-thinned ---
-        fresh, tails = workload.gen(rng_gen, wave, T, state.store.ring_tails)
-        if active is not None:
-            fresh = dataclasses.replace(
-                fresh,
-                op_key=jnp.where(active[:, None], fresh.op_key, -1),
-                op_kind=jnp.where(active[:, None], fresh.op_kind, t.NOP),
-                n_ops=jnp.where(active, fresh.n_ops, 0))
-        offered = poisson_offered(rng_arr, cfg.arrival_rate, T)
-        offered = jnp.minimum(offered, n_active)
-        arr_mask = jnp.arange(T, dtype=jnp.int32) < offered
-        ids = state.ol.next_id + jnp.arange(T, dtype=jnp.int32)
-        queue, n_adm, n_ovf = admission.enqueue(
-            ol.queue, fresh, jnp.full((T,), wave, jnp.int32),
-            jnp.zeros((T,), jnp.int32), ids, arr_mask)
+        with jax.named_scope("repro:gen"):
+            rng, rng_gen, rng_perm, rng_arr = jax.random.split(state.rng, 4)
+            fresh, tails = workload.gen(rng_gen, wave, T,
+                                        state.store.ring_tails)
+            if active is not None:
+                fresh = dataclasses.replace(
+                    fresh,
+                    op_key=jnp.where(active[:, None], fresh.op_key, -1),
+                    op_kind=jnp.where(active[:, None], fresh.op_kind,
+                                      t.NOP),
+                    n_ops=jnp.where(active, fresh.n_ops, 0))
+            offered = poisson_offered(rng_arr, cfg.arrival_rate, T)
+            offered = jnp.minimum(offered, n_active)
+            arr_mask = jnp.arange(T, dtype=jnp.int32) < offered
+            ids = state.ol.next_id + jnp.arange(T, dtype=jnp.int32)
 
-        # ---- admit: fill the lane grid FIFO from the queue -------------
-        queue, batch, admit_w, incarn, txn_id, got = admission.dequeue(
-            queue, T, n_active)
+        with jax.named_scope("repro:schedule"):
+            queue, n_adm, n_ovf = admission.enqueue(
+                ol.queue, fresh, jnp.full((T,), wave, jnp.int32),
+                jnp.zeros((T,), jnp.int32), ids, arr_mask)
+            # ---- admit: fill the lane grid FIFO from the queue ---------
+            queue, batch, admit_w, incarn, txn_id, got = admission.dequeue(
+                queue, T, n_active)
+            perm = jax.random.permutation(rng_perm, T).astype(jnp.uint32)
+            prio = claims.prio16(incarn, perm,
+                                 use_age=(cfg.cc == t.CC_SWISS))
         store = dataclasses.replace(state.store, ring_tails=tails)
-
-        perm = jax.random.permutation(rng_perm, T).astype(jnp.uint32)
-        prio = claims.prio16(incarn, perm, use_age=(cfg.cc == t.CC_SWISS))
 
         with jax.named_scope("repro:validate"):
             store, res = validator(store, batch, prio, wave, cfg)
-        commit = res.commit & got
-
-        if cfg.track_values:
-            vals = apply_values(store.values, batch, commit, prio)
-            store = dataclasses.replace(store, values=vals)
+            commit = res.commit & got
+            if cfg.track_values:
+                vals = apply_values(store.values, batch, commit, prio)
+                store = dataclasses.replace(store, values=vals)
 
         # ---- cost model (shared with the closed loop) ------------------
         with jax.named_scope("repro:cost"):
             lane_dt, has_write = _lane_cost(cfg, batch, commit, res)
-        lane_dt = jnp.where(got, lane_dt, 0.0)
+            lane_dt = jnp.where(got, lane_dt, 0.0)
 
-        # ---- retry incarnations / latency accounting -------------------
-        aborted = got & ~commit
-        retry = aborted & (incarn < cfg.max_incarnations)
-        inc_drop = aborted & ~retry
-        # Abort-cause attribution: the TERMINAL abort of a transaction at
-        # its incarnation cap is the one that ejects it from the system —
-        # reclassified CAUSE_INC_CAP (it dominates every validation
-        # cause), so cause[CAUSE_INC_CAP] == inc_drops exactly and the
-        # per-cause counts still sum to total aborts.
-        lane_cause = jnp.where(inc_drop, jnp.int32(t.CAUSE_INC_CAP),
-                               res.lane_cause())
-        causes_wave = t.cause_counts(lane_cause, aborted)
-        if cfg.track_conflicts:
-            hits, peak = _conflict_histogram(
-                cfg, state.conflict_hits, state.conflict_peak, batch, res)
-        else:
-            hits, peak = state.conflict_hits, state.conflict_peak
-        # Arrivals enqueued before the dequeue freed these lanes, so the
-        # re-enqueue can never overflow (module invariant); reenq_drops
-        # stays 0 and the conservation oracle asserts it.
-        queue, _, n_re_ovf = admission.enqueue(
-            queue, batch, admit_w, incarn + 1, txn_id, retry)
-        ttc = wave.astype(jnp.int32) - admit_w + 1
-        new_ol = admission.record_commits(
-            dataclasses.replace(
-                ol, queue=queue,
-                next_id=ol.next_id + offered,
-                offered=ol.offered + offered,
-                admitted=ol.admitted + n_adm,
-                arrival_drops=ol.arrival_drops + n_ovf,
-                inc_drops=ol.inc_drops
-                          + inc_drop.sum().astype(jnp.int32),
-                reenq_drops=ol.reenq_drops + n_re_ovf),
-            batch.txn_type, ttc, commit)
+        # ---- retry incarnations: aborted lanes re-enter the queue ------
+        with jax.named_scope("repro:schedule"):
+            aborted = got & ~commit
+            retry = aborted & (incarn < cfg.max_incarnations)
+            inc_drop = aborted & ~retry
+            # Arrivals enqueued before the dequeue freed these lanes, so
+            # the re-enqueue can never overflow (module invariant);
+            # reenq_drops stays 0 and the conservation oracle asserts it.
+            queue, _, n_re_ovf = admission.enqueue(
+                queue, batch, admit_w, incarn + 1, txn_id, retry)
 
-        # ---- metrics ---------------------------------------------------
-        committed = commit
-        commits_by_type = state.commits_by_type.at[batch.txn_type].add(
-            committed.astype(state.commits_by_type.dtype))
-        ro = ~has_write
-        new_state = EngineState(
-            rng=rng,
-            wave=wave + 1,
-            store=store,
-            pending=state.pending,           # unused in open loop: the
-            pending_live=state.pending_live,  # queue owns every retry
-            age=state.age,
-            lane_time=state.lane_time + lane_dt,
-            commits=state.commits
-                    + committed.sum().astype(state.commits.dtype),
-            aborts=state.aborts + aborted.sum().astype(state.aborts.dtype),
-            commits_by_type=commits_by_type,
-            wasted_time=state.wasted_time
-                        + jnp.where(committed, 0.0, lane_dt).sum(),
-            ext_events=state.ext_events + res.ext_count,
-            ro_commits=state.ro_commits
-                       + (committed & ro).sum().astype(state.ro_commits.dtype),
-            ro_aborts=state.ro_aborts
-                      + (aborted & ro).sum().astype(state.ro_aborts.dtype),
-            abort_causes=state.abort_causes + causes_wave,
-            conflict_hits=hits,
-            conflict_peak=peak,
-            ol=new_ol,
-        )
-        ys = (committed.sum().astype(jnp.int32),
-              aborted.sum().astype(jnp.int32),
-              offered, n_adm, n_ovf,
-              inc_drop.sum().astype(jnp.int32),
-              causes_wave, lane_dt.sum())
+        # ---- latency and metrics ---------------------------------------
+        with jax.named_scope("repro:account"):
+            # Abort-cause attribution: the TERMINAL abort of a transaction
+            # at its incarnation cap is the one that ejects it from the
+            # system — reclassified CAUSE_INC_CAP (it dominates every
+            # validation cause), so cause[CAUSE_INC_CAP] == inc_drops
+            # exactly and the per-cause counts still sum to total aborts.
+            lane_cause = jnp.where(inc_drop, jnp.int32(t.CAUSE_INC_CAP),
+                                   res.lane_cause())
+            causes_wave = t.cause_counts(lane_cause, aborted)
+            if cfg.track_conflicts:
+                hits, peak = _conflict_histogram(
+                    cfg, state.conflict_hits, state.conflict_peak, batch,
+                    res)
+            else:
+                hits, peak = state.conflict_hits, state.conflict_peak
+            ttc = wave.astype(jnp.int32) - admit_w + 1
+            new_ol = admission.record_commits(
+                dataclasses.replace(
+                    ol, queue=queue,
+                    next_id=ol.next_id + offered,
+                    offered=ol.offered + offered,
+                    admitted=ol.admitted + n_adm,
+                    arrival_drops=ol.arrival_drops + n_ovf,
+                    inc_drops=ol.inc_drops
+                              + inc_drop.sum().astype(jnp.int32),
+                    reenq_drops=ol.reenq_drops + n_re_ovf),
+                batch.txn_type, ttc, commit)
+
+            committed = commit
+            commits_by_type = state.commits_by_type.at[batch.txn_type].add(
+                committed.astype(state.commits_by_type.dtype))
+            ro = ~has_write
+            new_state = EngineState(
+                rng=rng,
+                wave=wave + 1,
+                store=store,
+                pending=state.pending,           # unused in open loop: the
+                pending_live=state.pending_live,  # queue owns every retry
+                age=state.age,
+                lane_time=state.lane_time + lane_dt,
+                commits=state.commits
+                        + committed.sum().astype(state.commits.dtype),
+                aborts=state.aborts
+                       + aborted.sum().astype(state.aborts.dtype),
+                commits_by_type=commits_by_type,
+                wasted_time=state.wasted_time
+                            + jnp.where(committed, 0.0, lane_dt).sum(),
+                ext_events=state.ext_events + res.ext_count,
+                ro_commits=state.ro_commits
+                           + (committed & ro).sum().astype(
+                               state.ro_commits.dtype),
+                ro_aborts=state.ro_aborts
+                          + (aborted & ro).sum().astype(
+                              state.ro_aborts.dtype),
+                abort_causes=state.abort_causes + causes_wave,
+                conflict_hits=hits,
+                conflict_peak=peak,
+                ol=new_ol,
+            )
+            ys = (committed.sum().astype(jnp.int32),
+                  aborted.sum().astype(jnp.int32),
+                  offered, n_adm, n_ovf,
+                  inc_drop.sum().astype(jnp.int32),
+                  causes_wave, lane_dt.sum())
         if trace:
             ys = ys + ((txn_id, incarn, got, admit_w, batch.op_key,
                         batch.op_kind, commit),)
@@ -536,7 +553,7 @@ class SweepPoint:
     p50_ttc: Optional[list] = None  # per-txn-class time-to-commit (waves)
     p99_ttc: Optional[list] = None
     abort_causes: Optional[list] = None  # int[N_ABORT_CAUSES] (types.CAUSE_*)
-    # Per-wave timeline (sweep(..., per_wave=True); analysis/trace.py):
+    # Per-wave counters (sweep(..., per_wave=True)):
     per_wave_commits: Optional[jax.Array] = None
     per_wave_aborts: Optional[jax.Array] = None
     per_wave_causes: Optional[jax.Array] = None
@@ -638,9 +655,9 @@ def sweep(cfg: EngineConfig, workload: Workload, n_waves: int, *,
                    ol.offered, ol.admitted, ol.arrival_drops, ol.inc_drops,
                    ol.queue.size, ol.lat_hist, state.abort_causes)
             if per_wave:
-                # Per-wave timeline (commits, aborts, cause deltas, sim
-                # us) for the trace exporter; the cause/us slots sit at
-                # different ys indices in the two traffic models.
+                # Per-wave counters (commits, aborts, cause deltas, sim
+                # us); the cause/us slots sit at different ys indices in
+                # the two traffic models.
                 ci, ui = (6, 7) if ccfg.open_loop else (2, 3)
                 out = out + (ys[0], ys[1], ys[ci], ys[ui])
             return out

@@ -13,7 +13,8 @@ at lane ``k & 127`` of row ``o * Nb * G + (k >> 7) * G + g``.  A record's
 ``RW = O * G`` words are one lane of RW rows, so kernels move RW single-row
 DMAs per record and see every op's word of row ``r`` at the op's lane.
 ``pack``/``unpack`` convert at the kernel boundary, so engine state keeps
-its ``[N, G]`` shape and both backends read the same tables.
+its ``[N, G]`` shape and both backends read the same tables; they and the
+per-op row conversions run under the named scope ``repro:relayout``.
 
 Ops are flattened lane-major (op ``t * K + k``) and walked in blocks of
 ``LBK = LB * K`` ops, where ``LB`` lanes per grid step makes LBK a
@@ -38,6 +39,9 @@ from jax.experimental.pallas import tpu as pltpu
 LANES = 128
 SENT = 0x7FFFFFFF       # cell id of masked ops in all-pairs compares
 SIGN = -0x80000000      # xor that maps uint32 order onto int32 order
+#: Named scope of every layout conversion at a kernel boundary; the
+#: benchmark reads its device time as ``relayout_share_pct``.
+RELAYOUT = "repro:relayout"
 
 
 # ------------------------------------------------------------------ layout
@@ -60,19 +64,22 @@ def pack(table: jax.Array) -> jax.Array:
     orders compile for minutes at 10M records."""
     Nb, O, G = _dims(table.shape)
     n = table.shape[0]
-    x = i32(table).reshape(n, O, G).transpose(1, 2, 0)
-    if Nb * LANES != n:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, Nb * LANES - n)))
-    x = x.reshape(O, G, Nb, LANES).transpose(0, 2, 1, 3)
-    return x.reshape(O * Nb * G, LANES)
+    with jax.named_scope(RELAYOUT):
+        x = i32(table).reshape(n, O, G).transpose(1, 2, 0)
+        if Nb * LANES != n:
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, Nb * LANES - n)))
+        x = x.reshape(O, G, Nb, LANES).transpose(0, 2, 1, 3)
+        return x.reshape(O * Nb * G, LANES)
 
 
 def unpack(packed: jax.Array, like: jax.Array) -> jax.Array:
     """Inverse of ``pack``: back to the shape and dtype of ``like``."""
     Nb, O, G = _dims(like.shape)
-    x = packed.reshape(O, Nb, G, LANES).transpose(0, 2, 1, 3)
-    x = x.reshape(O, G, Nb * LANES)[:, :, :like.shape[0]].transpose(2, 0, 1)
-    return jax.lax.bitcast_convert_type(x.reshape(like.shape), like.dtype)
+    with jax.named_scope(RELAYOUT):
+        x = packed.reshape(O, Nb, G, LANES).transpose(0, 2, 1, 3)
+        x = x.reshape(O, G, Nb * LANES)[:, :, :like.shape[0]]
+        x = x.transpose(2, 0, 1).reshape(like.shape)
+        return jax.lax.bitcast_convert_type(x, like.dtype)
 
 
 def row_offsets(shape) -> list[int]:
@@ -108,15 +115,17 @@ def op_rows(x: jax.Array, Tp: int, fill: int = 0) -> jax.Array:
     """``[T, K]`` per-op values -> lane-dense ``[1, Tp * K]`` int32 row,
     padded with ``fill`` for the masked lanes ``T .. Tp - 1``."""
     T, K = x.shape
-    x = i32(x)
-    if Tp != T:
-        x = jnp.pad(x, ((0, Tp - T), (0, 0)), constant_values=fill)
-    return x.reshape(1, Tp * K)
+    with jax.named_scope(RELAYOUT):
+        x = i32(x)
+        if Tp != T:
+            x = jnp.pad(x, ((0, Tp - T), (0, 0)), constant_values=fill)
+        return x.reshape(1, Tp * K)
 
 
 def from_rows(y: jax.Array, T: int, K: int) -> jax.Array:
     """``[1, Tp * K]`` kernel output row -> ``[T, K]``."""
-    return y.reshape(-1, K)[:T]
+    with jax.named_scope(RELAYOUT):
+        return y.reshape(-1, K)[:T]
 
 
 # --------------------------------------------------------- in-kernel math

@@ -17,7 +17,6 @@ from __future__ import annotations
 import argparse
 import functools
 import json
-import os
 import time
 
 
@@ -153,8 +152,7 @@ def run_grid(workload: str, ccs: list, grans, lanes: list, waves: int, *,
              write_frac: float = 0.5, ro_frac: float = 0.0,
              theta: float = 0.9, scan_frac: float = 0.0, scan_len: int = 0,
              arrival_rate: float = 0.0,
-             queue_cap: int = 0, max_incarnations: int = 0,
-             per_wave: bool = False, return_points: bool = False):
+             queue_cap: int = 0, max_incarnations: int = 0):
     """Run the whole benchmark grid in one jitted sweep; returns row dicts.
 
     ``wall_s`` in each row is the grid's wall time amortized over its rows
@@ -196,17 +194,13 @@ def run_grid(workload: str, ccs: list, grans, lanes: list, waves: int, *,
     t0 = time.time()
     points = sweep(cfg, wl, waves, ccs=[t.CC_IDS[c] for c in ccs],
                    grans=tuple(grans), lane_counts=tuple(lanes),
-                   seeds=(seed,), per_wave=per_wave)
+                   seeds=(seed,))
     wall = (time.time() - t0) / max(len(points), 1)
     rows = [_row(workload, t.CC_NAMES[p.cc], p, wall, backend,
                  slots=wl.slots, n_groups=wl.n_groups,
                  mv_depth=cfg.mv_depth, max_extent=cfg.max_extent,
                  bucket_size=cfg.bucket_size)
             for p in points]
-    if return_points:
-        # (rows, SweepPoints) — the points carry the per-wave timeline the
-        # Chrome-trace exporter consumes (analysis/trace.py).
-        return rows, points
     return rows
 
 
@@ -326,15 +320,6 @@ def main(argv=None):
                          "Order-status/Stock-level scan classes at this "
                          "stock window")
     ap.add_argument("--json", default=None)
-    ap.add_argument("--trace", nargs="?", const="reports/txn_trace.json",
-                    default=None, metavar="PATH",
-                    help="export the wave-level timeline as Chrome-trace "
-                         "JSON (analysis/trace.py; open in chrome://"
-                         "tracing or ui.perfetto.dev) — one process row "
-                         "per grid point, one slice per wave with commit/"
-                         "abort-cause deltas on the simulated-time axis; "
-                         "REPRO_TRACE=1 (or =<path>) enables the same "
-                         "without a flag")
     args = ap.parse_args(argv)
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -373,14 +358,8 @@ def main(argv=None):
     elif args.arrival_rate <= 0:
         ap.error(f"--arrival-rate must be > 0 (got {args.arrival_rate}); "
                  "omit the flag for the closed-loop retry buffer")
-    trace_path = args.trace
-    if trace_path is None:
-        env = os.environ.get("REPRO_TRACE", "")
-        if env and env != "0":
-            trace_path = (env if env not in ("1", "true")
-                          else "reports/txn_trace.json")
     grans = {"coarse": (0,), "fine": (1,), "both": (0, 1)}[args.granularity]
-    rows, points = run_grid(
+    rows = run_grid(
         args.workload, args.cc, grans, args.lanes, args.waves,
         scale=args.scale, n_keys=args.n_keys, seed=args.seed,
         backend=args.backend, mv_depth=args.mv_depth,
@@ -393,8 +372,7 @@ def main(argv=None):
         scan_len=args.scan_len or 0,
         arrival_rate=args.arrival_rate or 0.0,
         queue_cap=args.queue_cap or 0,
-        max_incarnations=args.max_incarnations or 0,
-        per_wave=bool(trace_path), return_points=True)
+        max_incarnations=args.max_incarnations or 0)
     for r in rows:
         line = (f"{r['workload']} {r['cc']:9s} "
                 f"{'fine' if r['granularity'] else 'coarse'} "
@@ -409,14 +387,6 @@ def main(argv=None):
     if args.json:
         with open(args.json, "w") as f:
             json.dump(rows, f, indent=1)
-    if trace_path:
-        from repro.analysis.trace import sweep_trace, write_trace
-        d = os.path.dirname(trace_path)
-        if d:
-            os.makedirs(d, exist_ok=True)
-        write_trace(trace_path, sweep_trace(points))
-        print(f"wrote Chrome trace -> {trace_path} ({len(points)} grid "
-              "points; load in chrome://tracing or ui.perfetto.dev)")
 
 
 if __name__ == "__main__":
