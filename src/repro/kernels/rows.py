@@ -183,13 +183,18 @@ def pick(rows: jax.Array, at: jax.Array) -> jax.Array:
 
 
 def row_dmas(start: bool, prow_ref, tbl_ref, buf_ref, sem, base, n, offs,
-             to_table: bool = False):
+             to_table: bool = False, ops=None):
     """Issue (``start``) or wait the row copies of a block: for word r
     (offset ``offs[r]``) of op j, packed row ``prow_ref[base + j] +
     offs[r]`` <-> scratch row ``r * n + j``.  A stream's copies are all in
-    flight together and share the semaphore ``sem``."""
-    for r, off in enumerate(offs):
-        def body(j, _, r=r, off=off):
+    flight together and share the semaphore ``sem``.
+
+    ``ops`` = (``list_ref``, ``count``) limits the copies to the block's
+    ops ``j = list_ref[base + i]``, ``i < count``; start and wait take
+    the same list, so each wait retires one started copy."""
+    def body(i, _):
+        j = i if ops is None else ops[0][base + i]
+        for r, off in enumerate(offs):
             src = tbl_ref.at[pl.ds(prow_ref[base + j] + off, 1)]
             dst = buf_ref.at[pl.ds(r * n + j, 1)]
             if to_table:
@@ -199,9 +204,9 @@ def row_dmas(start: bool, prow_ref, tbl_ref, buf_ref, sem, base, n, offs,
                 copy.start()
             else:
                 copy.wait()
-            return 0
+        return 0
 
-        jax.lax.fori_loop(0, n, body, 0)
+    jax.lax.fori_loop(0, n if ops is None else ops[1], body, 0)
 
 
 def any_spec():

@@ -13,21 +13,26 @@ with one table and the raw priorities as output.
 Tiling.  Tables are packed 128-lane rows (kernels/rows.py) in ANY memory
 space.  The grid walks blocks of ``LBK = LB * K`` ops (``LB`` whole
 lanes, LBK a multiple of 128): a step issues the packed-row fetches of
-all its ops back-to-back (the whole read stream in flight at once), waits
-once, runs the block's probe / verdict / install math in VMEM, and
-streams the writebacks out.
+all its live ops back-to-back (the whole read stream in flight at once),
+waits once, runs the block's probe / verdict / install math in VMEM, and
+streams the writebacks out.  Its scalar loops walk lists of the block's
+live ops and of its row leaders (``op_order``), never its masked ops.
 
-Correctness under the block tiling.  A block's row fetches all happen
-before any of its writebacks, so ops that share a packed row read the
-same pre-block row — the kernel therefore writes back *final* rows, the
-same bytes for every op on that row:
+Row leaders.  The launcher computes, per op, the in-block index of the
+first live op of its block on the same packed row (``row_leaders``; -1
+for a masked op).  A block's row fetches all happen before any of its
+writebacks, so every live op reads the same pre-block copy of its row
+(the probe reads each op's own copy); the *final* row is built in the
+leader's copy alone, and only leaders write back — one writeback per
+distinct packed row per block:
 
-  - claim install: fetched row, min-combined with the claim word of
-    every block write op on that packed row (a scalar loop over the
-    block's ops, each a masked vector min);
+  - claim install: each installing op min-combines its claim word into
+    one word of its leader's copy (a scalar loop over the block's live
+    ops, one (1, 128) row each);
   - version bump: fetched row + the block's committed-write count per
     cell — a 0/1 matrix product (same packed row x committed) @ (op lane
-    one-hot) on the MXU, exact for counts below 2^24.  Lane verdicts are
+    one-hot) on the MXU, exact for counts below 2^24; every copy gets
+    its row's full count, the leader's included.  Lane verdicts are
     block-local by construction (a block holds whole lanes).
 
 Cross-block installs are ordered by the sequential grid (a step's
@@ -36,8 +41,10 @@ of LATER blocks: the whole wave's claim cells and priorities ride along
 as lane-dense rows, and an all-pairs same-cell min over them (128 lanes
 at a time, folded elementwise, one lane reduce at the end) completes the
 row probe — sound under the monotone-wave-tag precondition checked by
-``ref.check_claim_tag_monotone``.  Masked ops fetch packed row 0 and
-write back the same final row 0 as any real op on it.
+``ref.check_claim_tag_monotone``.  Masked ops (key < 0, and the
+padding lanes) move no row: their scratch rows keep stale words, which
+the probe masks out and no writeback reads.  ``wave_row_copies`` counts
+the copies a wave issues.
 """
 from __future__ import annotations
 
@@ -84,34 +91,34 @@ def probe_col(words, ivw, ocell_c, grp_c, live_c, wcell_ref, wp16_ref,
     return jnp.where(live_c, jnp.minimum(pr, wave), NO_PRIO)
 
 
-def install_claims(buf, inst_s, prow_s, prow_c, lanes, ivw, base, LBK, G):
+def install_claims(buf, inst_s, lead_s, ivw, base, LBK, live):
     """Fold every installing block op's claim word (``inst`` = ``g << 23
-    | lane << 16 | prio16``, -1 when not installing) into every fetched
-    copy of its packed row: each op on a row ends with the same final
-    row."""
+    | lane << 16 | prio16``, -1 when not installing) into its row
+    leader's copy of group row ``g``: one (1, 128) row per op.  Walks the
+    block's live ops, ``live`` = (op list, count)."""
     tag = ivw << WAVE_SHIFT
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, rw.LANES), 1)
 
-    def body(j, _):
+    def body(i, _):
+        j = live[0][base + i]
         v = inst_s[base + j]
 
         @pl.when(v >= 0)
         def _():
             word = tag | (v & PRIO16_MASK)
-            m = (prow_c == prow_s[base + j]) & (lanes == ((v >> 16) & 127))
-            for r in range(G):
-                @pl.when((v >> 23) == r)
-                def _():
-                    sl = pl.ds(r * LBK, LBK)
-                    cur = buf[sl, :]
-                    buf[sl, :] = jnp.where(m, rw.umin(cur, word), cur)
+            at = pl.ds((v >> 23) * LBK + lead_s[base + j], 1)
+            cur = buf[at, :]
+            buf[at, :] = jnp.where(lanes == ((v >> 16) & 127),
+                                   rw.umin(cur, word), cur)
         return 0
 
-    jax.lax.fori_loop(0, LBK, body, 0)
+    jax.lax.fori_loop(0, live[1], body, 0)
 
 
 def _wave_kernel(fine, G, LBK, TKp, dual, bump, verdict, *refs):
     it = iter(refs)
-    prow_s, ivw_s, instw_s = next(it), next(it), next(it)
+    prow_s, lead_s, order_s, nlive_s, nlead_s, ivw_s, instw_s = (
+        next(it) for _ in range(7))
     instr_s = next(it) if dual else None
     (prow_b, lane_b, grp_b, ocell_b, prio_b, flags_b,
      lid_b) = (next(it) for _ in range(7))
@@ -125,16 +132,20 @@ def _wave_kernel(fine, G, LBK, TKp, dual, bump, verdict, *refs):
     scratch = [(next(it), next(it), next(it)) for _ in tables]
     offs = list(range(G))
 
-    base = pl.program_id(0) * LBK
+    blk = pl.program_id(0)
+    base = blk * LBK
     ivw = ivw_s[0]
+    live = (order_s, nlive_s[blk])       # leaders first, then other live
+    leaders = (order_s, nlead_s[blk])
+    dmas = functools.partial(rw.row_dmas, prow_ref=prow_s, base=base,
+                             n=LBK, offs=offs)
     for tbl, (buf, sem, _) in zip(tables, scratch):
-        rw.row_dmas(True, prow_s, tbl, buf, sem, base, LBK, offs)
+        dmas(True, tbl_ref=tbl, buf_ref=buf, sem=sem, ops=live)
     for tbl, (buf, sem, _) in zip(tables, scratch):
-        rw.row_dmas(False, prow_s, tbl, buf, sem, base, LBK, offs)
+        dmas(False, tbl_ref=tbl, buf_ref=buf, sem=sem, ops=live)
 
     ident = rw.eye(LBK)
     lanes = rw.lane_iota(LBK)
-    prow_c = rw.to_col(prow_b[...], ident)
     lane_c = rw.to_col(lane_b[...], ident)
     grp_c = rw.to_col(grp_b[...], ident)
     flags_c = rw.to_col(flags_b[...], ident)
@@ -164,13 +175,12 @@ def _wave_kernel(fine, G, LBK, TKp, dual, bump, verdict, *refs):
     else:
         outs[0][...] = rw.to_row(wprio, ident)
 
-    install_claims(scratch[0][0], instw_s, prow_s, prow_c, lanes, ivw, base,
-                   LBK, G)
+    install_claims(scratch[0][0], instw_s, lead_s, ivw, base, LBK, live)
     if dual:
-        install_claims(scratch[1][0], instr_s, prow_s, prow_c, lanes, ivw,
-                       base, LBK, G)
+        install_claims(scratch[1][0], instr_s, lead_s, ivw, base, LBK, live)
     if bump:
         buf = scratch[-1][0]
+        prow_c = rw.to_col(prow_b[...], ident)
         committed = ((flags_b[...] & _DOW) != 0) & (commit_r != 0)
         b = jnp.where(at, 1.0, 0.0).astype(jnp.float32)
         for r in offs:
@@ -181,12 +191,52 @@ def _wave_kernel(fine, G, LBK, TKp, dual, bump, verdict, *refs):
             buf[sl, :] = buf[sl, :] + cnt.astype(jnp.int32)
 
     for tbl, (buf, _, sem) in zip(tables, scratch):
-        rw.row_dmas(True, prow_s, tbl, buf, sem, base, LBK, offs,
-                    to_table=True)
+        dmas(True, tbl_ref=tbl, buf_ref=buf, sem=sem, to_table=True,
+             ops=leaders)
     # Writebacks must land before the next block fetches (sequential grid).
     for tbl, (buf, _, sem) in zip(tables, scratch):
-        rw.row_dmas(False, prow_s, tbl, buf, sem, base, LBK, offs,
-                    to_table=True)
+        dmas(False, tbl_ref=tbl, buf_ref=buf, sem=sem, to_table=True,
+             ops=leaders)
+
+
+def row_leaders(krow: jax.Array, LBK: int) -> jax.Array:
+    """Row-leader map of a padded wave: for each op of the lane-dense key
+    row ``krow`` ``[1, TKp]`` (padding -1), the in-block index of the
+    first live op of its block of ``LBK`` on the same packed row, or -1
+    for a masked op.  An all-pairs compare per block, in XLA."""
+    k = krow.reshape(-1, LBK)
+    live = k >= 0
+    blk = k >> 7
+    idx = jnp.arange(LBK, dtype=jnp.int32)
+    same = (blk[:, :, None] == blk[:, None, :]) & live[:, None, :]
+    first = jnp.min(jnp.where(same, idx, LBK), axis=2)
+    return jnp.where(live, first, -1).reshape(1, -1)
+
+
+def op_order(lead: jax.Array, LBK: int):
+    """The kernel's op lists from the leader map ``[TKp]``: each block's
+    in-block op indices with its row leaders first, then its other live
+    ops, then its masked ops; and per block the count of live ops and of
+    leaders.  The kernel's loops run over those prefixes alone."""
+    ld = lead.reshape(-1, LBK)
+    is_lead = ld == jnp.arange(LBK, dtype=jnp.int32)
+    rank = jnp.where(is_lead, 0, jnp.where(ld >= 0, 1, 2))
+    order = jnp.argsort(rank, axis=1).astype(jnp.int32)
+    return (order.reshape(-1), (ld >= 0).sum(axis=1, dtype=jnp.int32),
+            is_lead.sum(axis=1, dtype=jnp.int32))
+
+
+def wave_row_copies(keys: jax.Array, n_tables: int, G: int,
+                    lane_block: int = 0) -> tuple[jax.Array, jax.Array]:
+    """(row copies the kernel issues for a ``[T, K]`` wave on ``n_tables``
+    tables of ``G`` group rows, copies of the scheme that moved every op's
+    rows): a live op fetches its G rows per table, a row leader writes
+    them back; before, every op of the padded wave did both."""
+    LBK, Tp = rw.blocking(keys, lane_block)
+    lead = row_leaders(rw.op_rows(keys, Tp, fill=-1), LBK)[0]
+    _, n_live, n_lead = op_order(lead, LBK)
+    moved = n_live.sum() + n_lead.sum()
+    return n_tables * G * moved, 2 * n_tables * G * lead.shape[0]
 
 
 def _wave_call(tables, keys, groups, prio, do_w, do_r, flags, inv_wave,
@@ -207,7 +257,8 @@ def _wave_call(tables, keys, groups, prio, do_w, do_r, flags, inv_wave,
     do_w = do_w & live
     lid = jnp.broadcast_to(jnp.arange(Tp, dtype=jnp.int32)[:, None], (Tp, K))
 
-    prefetch = [row(prow)[0],
+    lead = row_leaders(row(keys, fill=-1), LBK)[0]
+    prefetch = [row(prow)[0], lead, *op_order(lead, LBK),
                 jnp.reshape(rw.i32(inv_wave.astype(jnp.uint32)), (1,)),
                 row(jnp.where(do_w, inst, -1), fill=-1)[0]]
     wave_rows = [row(jnp.where(do_w, ocell, rw.SENT), fill=rw.SENT), row(p16)]

@@ -7,8 +7,11 @@ many DMA semaphores, tables padded past device memory — so each kernel of
 the engine's main path is compiled here, one device of the described
 topology, at 1024 lanes x 16 slots (128 x 64 for TPC-C's slot width)
 against a 10M-record table, and the program must hold a Mosaic kernel
-(``tpu_custom_call``).  The topology is described inside a fixture, never
-at import, so every test worker collects the same tests.
+(``tpu_custom_call``).  The sharded OCC owner's ``wave_commit`` is
+compiled at its own shape too: 4 source chips x a 2048-op route buffer
+against one 2.5M-record shard of the claim table.  The topology is
+described inside a fixture, never at import, so every test worker
+collects the same tests.
 """
 import jax
 import jax.numpy as jnp
@@ -159,3 +162,19 @@ def test_kernel_compiles_for_v5e_tpcc_slots(name, one_chip,
                                            no_persistent_cache):
     fn, shapes = _cases(128, 64)[name]
     _compile(fn, shapes, one_chip)
+
+
+def test_wave_commit_compiles_for_v5e_owner(one_chip, no_persistent_cache):
+    """The owner's call: T = 4 sources, K = 2048 route slots (one block
+    of 2048 ops per source), one fine claim table of G = 2, no bump —
+    the shape whose scoped VMEM overflowed at a route capacity of 4096."""
+    T, K = 4, 2048
+    ops = lambda dt: ((T, K), dt)
+
+    def f(cw, keys, groups, prio, dow, cw_m):
+        return wave_commit_pallas(cw, None, None, keys, groups, prio, dow,
+                                  None, cw_m, None, None, None,
+                                  jnp.uint32(1), fine=True, dual=False,
+                                  bump=False)
+    _compile(f, [((N // 4, G), U32), ops(I32), ops(I32), ops(U32), ops(B),
+                 ops(B)], one_chip)

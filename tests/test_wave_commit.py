@@ -23,6 +23,7 @@ from repro.core.engine import run, sweep
 from repro.core.types import EngineConfig, TxnBatch, store_init
 from repro.kernels import ops, ref
 from repro.kernels.rows import pick_lane_block
+from repro.kernels.wave_commit import wave_row_copies
 from repro.workloads import YCSBWorkload
 
 RNG = np.random.default_rng(7)
@@ -34,15 +35,16 @@ WL = YCSBWorkload.make(n_keys=256)
 
 
 # ------------------------------------------------------- oracle parity
-def _op_inputs(T, K, N, G, wave, dup=True, masked=True):
+def _op_inputs(T, K, N, G, wave, dup=True, masked=True, keys=None):
     """Random op tensors with duplicate cells and masked (key < 0) ops
-    baked in, plus claim tables seeded with BOTH dead older-wave claims
-    and live same-wave claims — the fetched-row probe term and the
-    all-pairs wave term must both fire."""
-    keys = RNG.integers(0, max(2, N // 8) if dup else N, (T, K),
-                        dtype=np.int32)
-    if masked:
-        keys[RNG.random((T, K)) < 0.3] = -1
+    baked in (or the given ``keys``), plus claim tables seeded with BOTH
+    dead older-wave claims and live same-wave claims — the fetched-row
+    probe term and the all-pairs wave term must both fire."""
+    if keys is None:
+        keys = RNG.integers(0, max(2, N // 8) if dup else N, (T, K),
+                            dtype=np.int32)
+        if masked:
+            keys[RNG.random((T, K)) < 0.3] = -1
     groups = RNG.integers(0, G, (T, K), dtype=np.int32)
     prio = RNG.integers(0, 0xFFFF, (T, K), dtype=np.uint32)
 
@@ -75,7 +77,12 @@ def test_wave_commit_pallas_matches_oracle(fine, dual, bump, lane_block):
     with duplicate cells, masked ops, live and dead table claims, and
     every lane-block width (0 = auto)."""
     T, K, N, G, wave = 8, 4, 64, 3, 5
-    keys, groups, prio, cw, cr, wts, masks = _op_inputs(T, K, N, G, wave)
+    _assert_parity(_op_inputs(T, K, N, G, wave), wave, fine, dual, bump,
+                   lane_block)
+
+
+def _assert_parity(inputs, wave, fine, dual, bump, lane_block):
+    keys, groups, prio, cw, cr, wts, masks = inputs
     do_w, do_r, check_w, check_w2, check_r, extra = masks
     args = (cw, cr if dual else None, wts if bump else None, keys, groups,
             prio, do_w, do_r if dual else None, check_w, check_w2,
@@ -89,6 +96,85 @@ def test_wave_commit_pallas_matches_oracle(fine, dual, bump, lane_block):
             assert y is None, name
             continue
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y), name)
+
+
+def _layout_keys(case):
+    """(keys int32[T, K], lane_block) of a hand-built wave over 1024
+    records (8 packed block rows of 128 records) that stresses the row
+    leaders: which ops fetch, which copy installs, which writes back."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+
+    def rand(shape, lo=0, hi=1024):
+        return rng.integers(lo, hi, shape, dtype=np.int32)
+
+    if case == "masked_block":       # blocks of 8 lanes; block 0 masked
+        keys = rand((12, 16))        # lanes 12-15 pad block 1
+        keys[:8] = -1
+        return keys, 0
+    if case == "one_row_block":      # block 0 on packed row 3, duplicates
+        keys = rand((16, 16))
+        keys[:8] = rand((8, 16), 384, 384 + 24)
+        return keys, 0
+    if case == "row_across_blocks":  # row 5 in each of three blocks
+        keys = rand((24, 16))
+        keys[:, ::3] = rand((24, 6), 640, 768)
+        keys[rng.random(keys.shape) < 0.3] = -1
+        return keys, 0
+    if case == "rw_one_cell":        # writes and reads of one cell
+        keys = rand((16, 16))
+        keys[:8, :12] = 300
+        return keys, 0
+    if case == "tpcc_tiling":        # K = 64: 2 lanes a block, 61% masked
+        keys = rand((6, 64), 0, 300)
+        keys[rng.random(keys.shape) < 0.61] = -1
+        return keys, 0
+    if case == "owner_tiling":       # wide K: 1 lane a block, half-empty
+        keys = rand((4, 256))
+        keys[:, 128:] = -1
+        keys[3] = -1
+        return keys, 0
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize("case", ["masked_block", "one_row_block",
+                                  "row_across_blocks", "rw_one_cell",
+                                  "tpcc_tiling", "owner_tiling"])
+@pytest.mark.parametrize("fine", [False, True])
+@pytest.mark.parametrize("dual,bump", [(False, False), (False, True),
+                                       (True, True)])
+def test_wave_commit_pallas_matches_oracle_row_leaders(case, fine, dual,
+                                                       bump):
+    """The same bit-identity on hand-built waves that exercise the row
+    leaders: an all-masked block, a block on one packed row, a row in
+    several blocks, writes and reads of one cell in one block, and the
+    TPC-C (2 lanes x 64 slots) and sharded-owner (1 lane x a wide
+    route buffer) tilings."""
+    keys, lane_block = _layout_keys(case)
+    T, K = keys.shape
+    wave = 5
+    _assert_parity(_op_inputs(T, K, 1024, 2, wave, keys=keys), wave, fine,
+                   dual, bump, lane_block)
+
+
+def test_wave_row_copies():
+    """The copy counter on a hand-built wave of two blocks (2 lanes x 64
+    slots each; lane 3 pads): six live ops on three packed rows in block
+    0, one live op in block 1.  A live op fetches, a row leader writes
+    back, G rows per table; before, every op of the padded wave did
+    both.  An all-live wave on distinct rows issues what it did before."""
+    keys = np.full((3, 64), -1, np.int32)
+    keys[0, :3] = [0, 1, 130]
+    keys[1, :3] = [5, 260, 131]
+    keys[2, 0] = 7
+    new, old = wave_row_copies(jnp.asarray(keys), n_tables=2, G=2)
+    assert (int(new), int(old)) == (2 * 2 * (7 + 4), 2 * 2 * 2 * 256)
+    # one block of 4 lanes: lane 2's op shares packed row 0 with lane 0
+    new, old = wave_row_copies(jnp.asarray(keys), n_tables=1, G=3,
+                               lane_block=4)
+    assert (int(new), int(old)) == (3 * (7 + 3), 2 * 3 * 256)
+    distinct = jnp.arange(128, dtype=jnp.int32).reshape(2, 64) * 128
+    new, old = wave_row_copies(distinct, n_tables=1, G=2)
+    assert int(new) == int(old) == 2 * 2 * 128
 
 
 def test_wave_commit_oracle_semantics():
