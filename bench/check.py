@@ -1,11 +1,13 @@
 """Decides ``correct``: the waves the window ran, against the reference.
 
 For each checked chunk the reference runs the chunk's waves from the
-traffic the window drove (the generator's transactions and serial orders,
-made again from the seed) and the comparison counts where the program
-differs:
+traffic the window drove (the generator's transactions and, on one chip,
+lane permutations, made again from the seed; on four chips the serial
+orders) and the comparison counts where the program differs:
 
 - ``lanes_wrong``: lanes whose commit verdict differs;
+- ``ages_wrong`` (one chip): lanes and waves whose retry age, which the
+  commit latency reads, is not the one the reference counts;
 - ``causes_wrong``: the sum over waves of |program - reference| per abort
   cause;
 - ``versions_wrong``: version-word cells whose change over the chunk is not
@@ -15,6 +17,11 @@ differs:
 - ``ring_wrong`` (multi-version): records whose ring head or begin row is
   not the reference's installs applied to the chunk's starting ring.
 
+On one chip the reference counts the lanes' retry ages itself, from the
+ages the chunk started with, and builds each wave's serial order from
+them and the permutation under the configuration's ``priority`` rule
+(``bench/reference/order.py``; "random" where the file names none).
+
 Every limit is 0: each is an exact comparison.  The tables are encoded as
 the engine stores them: a claim word is ``(0xFFFF - (wave & 0xFFFF)) << 16
 | priority``; the ring's begin row of an install in wave w is ``w + 1``.
@@ -23,10 +30,11 @@ from __future__ import annotations
 
 import numpy as np
 
+from bench.reference import order
 from bench.reference import wave as ref
 
-LIMITS = {"lanes_wrong": 0, "causes_wrong": 0, "versions_wrong": 0,
-          "claims_wrong": 0, "ring_wrong": 0}
+LIMITS = {"lanes_wrong": 0, "ages_wrong": 0, "causes_wrong": 0,
+          "versions_wrong": 0, "claims_wrong": 0, "ring_wrong": 0}
 
 
 def claim_word(wave_no: int, prio):
@@ -105,40 +113,51 @@ class TableModel:
 def check_engine_chunk(config: dict, n_groups: int, inp: dict,
                        on_wave=None) -> dict:
     """Counts for one chunk of the one-chip engine.  The reference runs
-    the chunk's waves itself: each lane runs a fresh transaction, or
-    retries the one the reference aborted in the wave before.
-    ``on_wave(key, group, kind, commit)`` sees each wave it ran."""
+    the chunk's waves itself: each lane runs a fresh transaction at age
+    0, or retries the one the reference aborted in the wave before, one
+    age older; the wave's serial order follows the configuration's
+    ``priority`` rule.  ``on_wave(key, group, kind, commit)`` sees each
+    wave it ran."""
     tr, rec, pend = inp["traffic"], inp["rec"], inp["pending"]
     mv = config["cc"] == "mvocc"
+    rule = config.get("priority", order.DEFAULT)
     model = TableModel(inp["before"]["wts"].shape, n_groups, mv)
     key, group, kind = pend["key"], pend["group"], pend["kind"]
     retry = pend["live"].astype(bool)
-    lanes_wrong = causes_wrong = 0
+    age = pend["age"].astype(np.int64)
+    lanes_wrong = ages_wrong = causes_wrong = 0
     for i in range(tr["key"].shape[0]):
         w = int(tr["wave"][i])
         sel = retry[:, None]
         key = np.where(sel, key, tr["key"][i])
         group = np.where(sel, group, tr["group"][i]).astype(np.int64)
         kind = np.where(sel, kind, tr["kind"][i]).astype(np.int64)
-        prio = tr["prio"][i].astype(np.int64)
+        prio = order.priority(rule, age, tr["perm"][i])
         out = ref.wave(config["cc"], key, group, kind, prio,
                        n_groups=n_groups,
                        fine=config["granularity"] == "fine")
         lanes_wrong += int(np.count_nonzero(out["commit"]
                                             != rec["commit"][i]))
+        ages_wrong += int(np.count_nonzero(age != rec["age"][i]))
         causes_wrong += int(np.abs(out["causes"]
                                    - rec["causes"][i]).sum())
         model.add(w, key, group, prio, out)
         if on_wave is not None:
             on_wave(key, group, kind, out["commit"])
         retry = ~out["commit"]
-    res = {"lanes_wrong": lanes_wrong, "causes_wrong": causes_wrong}
+        age = order.next_age(age, out["commit"])
+    res = {"lanes_wrong": lanes_wrong, "ages_wrong": ages_wrong,
+           "causes_wrong": causes_wrong}
     res.update(model.compare(inp["before"], inp["after"]))
     return res
 
 
 def check_sharded_chunk(config: dict, geo: dict, inp: dict) -> dict:
-    """Counts for one chunk of the routed four-chip wave."""
+    """Counts for one chunk of the routed four-chip wave.  Its runner
+    never retries, so every lane runs at age 0 in each wave and both
+    ``priority`` rules give the permutation's order: the harness's
+    traffic (``bench/gen_ycsb.py``) hands the program and the reference
+    the same finished words."""
     tr, rec = inp["traffic"], inp["rec"]
     model = TableModel(inp["before"]["wts"].shape, geo["n_groups"], False)
     lanes_wrong = causes_wrong = 0

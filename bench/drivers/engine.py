@@ -111,19 +111,19 @@ class EngineDriver:
             return jax.lax.scan(body, state, None, length=self.waves)
 
         def traffic(rng, wave, tails):
-            """The chunk's fresh transactions and serial orders, made
+            """The chunk's fresh transactions and lane permutations, made
             again from the key the chunk started with, as the wave step
             makes them: split the key in three, the second draws the
-            transactions, the third permutes the lanes."""
+            transactions, the third permutes the lanes.  The serial
+            order is the check's to build, from the permutation and the
+            retry ages it counts itself."""
             def one(carry, _):
                 rng, wave, tails = carry
                 nxt, r_gen, r_perm = jax.random.split(rng, 3)
                 fresh, tails = wl.gen(r_gen, wave, self.lanes, tails)
                 perm = jax.random.permutation(r_perm, self.lanes)
-                prio = (jnp.uint32(63) << 10) | (
-                    perm.astype(jnp.uint32) & jnp.uint32(1023))
                 out = dict(key=fresh.op_key, group=fresh.op_group,
-                           kind=fresh.op_kind, prio=prio, wave=wave)
+                           kind=fresh.op_kind, perm=perm, wave=wave)
                 return (nxt, wave + 1, tails), out
             return jax.lax.scan(one, (rng, wave, tails), None,
                                 length=self.waves)[1]
@@ -158,16 +158,19 @@ class EngineDriver:
 
     def check_inputs(self, c: Chunk) -> dict:
         """What the reference and the comparison need of one chunk, on
-        the host: the retry buffer the chunk started from, the fresh
-        transactions and serial orders of its waves, the program's
-        verdicts and causes, and its tables before and after."""
+        the host: the retry buffer the chunk started from with its
+        lanes' retry ages (0 where no lane retries), the fresh
+        transactions and lane permutations of its waves, the program's
+        verdicts, ages and causes, and its tables before and after."""
         import jax
+        import jax.numpy as jnp
         s = c.state_in
         p = s.pending
         return jax.device_get({
             "traffic": self._traffic(s.rng, s.wave, s.store.ring_tails),
             "pending": {"key": p.op_key, "group": p.op_group,
-                        "kind": p.op_kind, "live": s.pending_live},
+                        "kind": p.op_kind, "live": s.pending_live,
+                        "age": jnp.where(s.pending_live, s.age, 0)},
             "rec": c.rec,
             "before": self.tables(c.state_in),
             "after": self.tables(c.state_out)})

@@ -9,6 +9,8 @@ import importlib.util
 import json
 from pathlib import Path
 
+from bench.reference import order
+
 #: The checkout root: the directory that holds ``BENCHMARK.json``.
 ROOT = Path(__file__).resolve().parent.parent
 
@@ -43,6 +45,9 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     if config.get("guarantee") != "serializable":
         raise ValueError(f"{w['config']}: every configuration states "
                          "guarantee 'serializable'")
+    if config.get("priority", order.DEFAULT) not in order.RULES:
+        raise ValueError(f"{w['config']}: priority "
+                         f"{config['priority']!r} is none of {order.RULES}")
     return Cell(
         name=name, chips=int(w["chips"]), config=config, mix=mix,
         end_to_end=[m for m in bench["end_to_end"] if _reports(m, name)],

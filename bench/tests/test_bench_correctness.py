@@ -49,6 +49,13 @@ def _answer_altered(step):
     return broken
 
 
+def _age_altered(step):
+    def broken(state, x):
+        new, ys = step(state, x)
+        return dataclasses.replace(new, age=new.age + 1), ys
+    return broken
+
+
 FAULTS = {
     "state_unchanged": lambda make: lambda cfg, wl, active=None:
         _state_unchanged(make(cfg, wl, active)),
@@ -56,6 +63,10 @@ FAULTS = {
         make(cfg, wl, jax.numpy.arange(cfg.lanes) < cfg.lanes // 2),
     "answer_altered": lambda make: lambda cfg, wl, active=None:
         _answer_altered(make(cfg, wl, active)),
+    # Every verdict stands; only the retry ages that the commit latency
+    # reads are off, from the second wave of a chunk on.
+    "age_altered": lambda make: lambda cfg, wl, active=None:
+        _age_altered(make(cfg, wl, active)),
 }
 
 
@@ -69,6 +80,8 @@ def test_fault_under_the_harness_is_not_correct(tmp_path, monkeypatch,
     name = tiny.make_root(tmp_path, tiny.CONFIGS[which], "ycsb-a")
     ok, checks = _correct(tmp_path, name)
     assert not ok, checks
+    if fault == "age_altered":
+        assert checks["ages_wrong"]["value"] > 0, checks
 
 
 _SHARDED = textwrap.dedent("""

@@ -1,12 +1,14 @@
 """The benchmark's arithmetic on hand-made inputs: the trace reduction,
-commit latency, the roofline bytes and peak, the copied generator."""
+commit latency, the roofline bytes and peak, the copied generator; and
+what ``load_cell`` refuses."""
 import json
 from pathlib import Path
 
 import numpy as np
 import pytest
 
-from bench import latency, roofline, trace_reduce
+from bench import latency, roofline, spec, trace_reduce
+from bench.tests import tiny
 
 _RAW = json.loads((Path(__file__).parent / "data" /
                    "trace_small.json").read_text())
@@ -147,3 +149,18 @@ def test_tpu_event_names_and_control_flow_ops():
     assert trace_reduce._is_exchange("all-to-all.3")
     assert trace_reduce._is_exchange("all_to_all.37")
     assert not trace_reduce._is_exchange("fusion.137")
+
+
+@pytest.mark.parametrize("priority,ok", [(None, True), ("random", True),
+                                         ("age", True), ("oldest", False)])
+def test_load_cell_checks_the_priority_rule(tmp_path, priority, ok):
+    config = dict(tiny.CONFIGS["mvocc"])
+    if priority is not None:
+        config["priority"] = priority
+    name = tiny.make_root(tmp_path, config, "ycsb-a")
+    if ok:
+        assert spec.load_cell(name, tmp_path).config.get(
+            "priority", "random") == (priority or "random")
+    else:
+        with pytest.raises(ValueError, match="priority"):
+            spec.load_cell(name, tmp_path)
